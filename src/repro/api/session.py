@@ -62,6 +62,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import use_abstract_mesh
 
 from repro.api.artifacts import (
     CompiledStep, ReplanResult, ShardingPlan, TrainReport, TunePlan,
@@ -70,7 +71,6 @@ from repro.api.callbacks import CallbackRegistry
 from repro.api.events import DriftDetected, FleetEvent, WorkerJoined, WorkerLost
 from repro.api.fleet import FleetSpec
 from repro.checkpoint.manager import CheckpointManager, ClusterCheckpointManager
-from repro.compat import set_mesh as compat_set_mesh
 from repro.core.hetero import BatchSchedule, schedule_from_tune
 from repro.core.load_balance import EpochPlan, plan_epoch
 from repro.core.privacy import PlacementManifest, Shard, place
@@ -163,8 +163,8 @@ class Session:
         self.storage: StorageSpec = storage or spec_storage or StorageSpec()
         # cluster mode: the spec travels on the FleetSpec; the live process
         # identity (ClusterContext) is attached by the WorkerRuntime after
-        # the jax.distributed handshake.  No context attached = the
-        # repro.compat single-process fallback: same stages, one process.
+        # the jax.distributed handshake.  No context attached = one
+        # process: same stages.
         self.cluster_spec: Optional[ClusterSpec] = (
             fleet.cluster if isinstance(fleet, FleetSpec) else None
         )
@@ -497,7 +497,7 @@ class Session:
                     # model's logical-axis activation constraints resolve
                     # against the same (possibly overridden) rules that
                     # produced the argument shardings — not the defaults
-                    with use_rules(plan.rules), compat_set_mesh(mesh):
+                    with use_rules(plan.rules), use_abstract_mesh(mesh.abstract_mesh):
                         return step(params, opt_state, batch)
 
                 in_sh = (plan.params, plan.opt, plan.batch)
@@ -561,11 +561,11 @@ class Session:
         )
 
         def grad_in_mesh(params, batch):
-            with use_rules(lp.rules), compat_set_mesh(lp.mesh):
+            with use_rules(lp.rules), use_abstract_mesh(lp.mesh.abstract_mesh):
                 return grad_step(params, batch)
 
         def apply_in_mesh(params, opt_state, bucket_vecs, sums):
-            with use_rules(lp.rules), compat_set_mesh(lp.mesh):
+            with use_rules(lp.rules), use_abstract_mesh(lp.mesh.abstract_mesh):
                 return apply_step(params, opt_state, bucket_vecs, sums)
 
         vec_sh = tuple(lp.replicated for _ in groups)

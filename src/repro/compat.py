@@ -1,30 +1,13 @@
-"""jax version compatibility: one import site for APIs that moved.
+"""The few jax runtime helpers the repo shares (targets jax 0.9.0 only).
 
-The repo targets the modern mesh API (``jax.sharding.AxisType``,
-``jax.set_mesh``, ``jax.make_mesh(..., axis_types=...)``,
-``jax.sharding.get_abstract_mesh``), but must also run on jax 0.4.x where
-none of those exist.  Every call site imports the equivalents from here
-instead of feature-testing jax inline:
-
-  * :func:`make_mesh` — builds an Auto-axis mesh on both API generations.
-  * :func:`set_mesh` — context manager activating a mesh; on 0.4.x the
-    ``Mesh`` object itself is the context manager.
-  * :func:`get_abstract_mesh` — the mesh active at trace time, or ``None``;
-    on 0.4.x this is the thread-local *physical* mesh, which is strictly
-    richer (it also carries devices), so callers treat both uniformly.
-  * :func:`constraint_sharding` — wraps a PartitionSpec for
-    ``with_sharding_constraint``: bare spec under an abstract mesh,
-    ``NamedSharding`` when the mesh is physical (0.4.x requirement outside
-    a mesh context).
-
-Multi-process (cluster) execution goes through the same funnel:
-
+  * :func:`make_mesh` — ``jax.make_mesh`` with every axis ``Auto``, so GSPMD
+    propagates shardings the model code leaves open.
+  * :func:`get_abstract_mesh` — the mesh active at trace time, or ``None``
+    outside one.  ``Session`` traces its step under
+    ``jax.sharding.use_abstract_mesh``; ``jax.set_mesh`` may only be
+    entered outside ``jax.jit``.
   * :func:`distributed_initialize` — the ``jax.distributed.initialize``
-    handshake with a single-process fallback: when the runtime has no
-    ``jax.distributed`` (or the coordinator is unreachable) the caller gets
-    ``False`` back and runs the exact same code path on one process.
-  * :func:`process_index` / :func:`process_count` — safe on every jax
-    generation, before or after distributed init.
+    handshake for a multi-process job; a one-process job skips it.
   * :func:`multiprocess_compute_supported` — whether jit computations may
     SPAN processes on this backend.  CPU jaxlib can hold a global mesh,
     build per-host addressable shards, and assemble global arrays — but not
@@ -36,111 +19,43 @@ Multi-process (cluster) execution goes through the same funnel:
 """
 from __future__ import annotations
 
-from typing import Optional, Sequence, Union
+from typing import Optional, Sequence
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec
-
-try:  # jax >= 0.6
-    from jax.sharding import AxisType  # type: ignore[attr-defined]
-except ImportError:  # jax 0.4.x: meshes are implicitly Auto
-    AxisType = None
-
-HAS_AXIS_TYPES = AxisType is not None
+from jax.sharding import AbstractMesh, AxisType, Mesh
 
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str]) -> Mesh:
-    """``jax.make_mesh`` with Auto axis types on any jax generation."""
-    if HAS_AXIS_TYPES:
-        return jax.make_mesh(
-            tuple(axis_shapes), tuple(axis_names),
-            axis_types=(AxisType.Auto,) * len(axis_names),
-        )
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names))
+    """``jax.make_mesh`` with Auto axis types."""
+    return jax.make_mesh(
+        tuple(axis_shapes), tuple(axis_names),
+        axis_types=(AxisType.Auto,) * len(axis_names),
+    )
 
 
-def set_mesh(mesh: Mesh):
-    """Context manager that makes ``mesh`` the ambient mesh.
-
-    jax >= 0.6 exposes ``jax.set_mesh``; on 0.4.x entering the ``Mesh``
-    object itself installs it as the thread-local physical mesh, which is
-    what ``get_abstract_mesh`` below reads back.
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
-
-
-def get_abstract_mesh():
-    """The ambient mesh visible at trace time, or ``None`` when outside one.
-
-    Returns the AbstractMesh on jax >= 0.6 and the thread-local physical
-    ``Mesh`` on 0.4.x.  Both expose ``axis_names`` and ``shape``.
-    """
-    try:
-        m = jax.sharding.get_abstract_mesh()  # type: ignore[attr-defined]
-        return None if m is None or m.empty else m
-    except AttributeError:
-        pass
-    try:
-        from jax.interpreters import pxla
-
-        m = pxla.thread_resources.env.physical_mesh
-        return None if m.empty else m
-    except Exception:
-        return None
-
-
-def axis_size(axis_name: str) -> int:
-    """``jax.lax.axis_size`` (>= 0.5); falls back to the bound axis frame.
-
-    On 0.4.x ``jax.core.axis_frame`` returns the size int directly.
-    """
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    frame = jax.core.axis_frame(axis_name)  # type: ignore[attr-defined]
-    return frame if isinstance(frame, int) else frame.size
-
-
-def process_index() -> int:
-    """This process's id in the distributed job (0 when single-process)."""
-    try:
-        return int(jax.process_index())
-    except Exception:
-        return 0
-
-
-def process_count() -> int:
-    """How many processes share the global device view (1 single-process)."""
-    try:
-        return int(jax.process_count())
-    except Exception:
-        return 1
+def get_abstract_mesh() -> Optional[AbstractMesh]:
+    """The ambient mesh visible at trace time, or ``None`` outside one."""
+    m = jax.sharding.get_abstract_mesh()
+    return None if m.empty else m
 
 
 def distributed_initialize(
     coordinator_address: str, num_processes: int, process_id: int,
 ) -> bool:
-    """``jax.distributed.initialize`` with a single-process fallback.
+    """``jax.distributed.initialize`` for a multi-process job.
 
-    Returns True when the handshake succeeded and the runtime now holds the
-    GLOBAL device view (``jax.devices()`` spans all processes,
-    ``jax.local_devices()`` is this host's slice).  Returns False when the
-    runtime cannot do distributed init at all (no ``jax.distributed``) —
-    callers then run the identical code on the single-process view.
+    Returns True when the runtime now holds the GLOBAL device view
+    (``jax.devices()`` spans all processes, ``jax.local_devices()`` is this
+    host's slice), False for a one-process job, which needs no handshake.
     Idempotent: a second call on an initialized runtime is a no-op True.
     """
     if num_processes <= 1:
         return False
-    dist = getattr(jax, "distributed", None)
-    if dist is None or not hasattr(dist, "initialize"):
-        return False
     # NB: do NOT probe jax.process_count() here — it initializes the
     # backend, after which jax.distributed refuses the handshake
-    state = getattr(dist, "global_state", None)
-    if state is not None and getattr(state, "client", None) is not None:
+    if jax.distributed.is_initialized():
         return True          # already initialized (e.g. by the launcher)
-    dist.initialize(
+    jax.distributed.initialize(
         coordinator_address=coordinator_address,
         num_processes=num_processes,
         process_id=process_id,
@@ -155,20 +70,4 @@ def multiprocess_compute_supported() -> bool:
     view, cross-process array metadata) but refuses to execute multiprocess
     XLA programs.  TPU/GPU backends execute them natively.
     """
-    try:
-        return jax.default_backend() != "cpu"
-    except Exception:
-        return False
-
-
-def constraint_sharding(
-    mesh, spec: PartitionSpec
-) -> Union[PartitionSpec, NamedSharding]:
-    """What to hand ``with_sharding_constraint`` for ``spec`` under ``mesh``.
-
-    A physical mesh (0.4.x path) needs an explicit ``NamedSharding``; an
-    abstract mesh (>= 0.6) resolves the bare spec itself.
-    """
-    if isinstance(mesh, Mesh):
-        return NamedSharding(mesh, spec)
-    return spec
+    return jax.default_backend() != "cpu"

@@ -364,10 +364,7 @@ class ClusterSpec:
     auto-pick free ones at launch.  ``membership_dir`` is where worker
     heartbeats land for the :class:`~repro.api.membership.MembershipWatcher`
     (a fresh tempdir when omitted).  ``transport`` selects the gradient
-    reduction path (see :class:`TransportSpec`).  ``compile_cache_dir``
-    points every worker at a shared persistent XLA compilation cache
-    (``None`` = a stable per-user tempdir; repeated launches of the same
-    shapes skip recompiles).
+    reduction path (see :class:`TransportSpec`).
     """
 
     processes: int = 1
@@ -377,7 +374,6 @@ class ClusterSpec:
     membership_dir: Optional[str] = None
     heartbeat_interval: float = 0.25
     transport: TransportSpec = dataclasses.field(default_factory=TransportSpec)
-    compile_cache_dir: Optional[str] = None
 
     def __post_init__(self):
         if self.processes < 1:

@@ -28,7 +28,6 @@ from typing import Callable, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.compat import axis_size
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.kernels import ops as kops
@@ -50,7 +49,7 @@ def ring_allreduce(x: jax.Array, axis: str) -> jax.Array:
     2(n-1)/n of the payload, the bandwidth-optimal schedule the paper's
     Horovod uses.
     """
-    n = axis_size(axis)
+    n = jax.lax.axis_size(axis)
     if n == 1:
         return x
     idx = jax.lax.axis_index(axis)
@@ -99,7 +98,7 @@ def hierarchical_allreduce(
     fleet-scale schedule, here explicit so the roofline's collective term can
     attribute bytes to the right fabric.
     """
-    n_intra = axis_size(intra_axis)
+    n_intra = jax.lax.axis_size(intra_axis)
     size = x.shape[0]
     pad = (-size) % n_intra
     if pad:
@@ -124,7 +123,6 @@ def compressed_allreduce(
     *,
     axis: str,
     rows: int = 256,
-    interpret: bool = True,
 ) -> Tuple[jax.Array, jax.Array]:
     """Quantized allreduce with error feedback.
 
@@ -143,7 +141,7 @@ def compressed_allreduce(
         y2 = y
     mat = y2.reshape(rows, -1)
     q, scale = kops.quantize_int8(
-        mat, noise.reshape(rows, -1), interpret=interpret
+        mat, noise.reshape(rows, -1), interpret=kops.interpret_default()
     )
     deq = kops.dequantize_int8(q, scale).reshape(-1)[:size]
     new_residual = y - deq

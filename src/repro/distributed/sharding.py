@@ -19,7 +19,7 @@ from typing import Any, Dict, Optional, Sequence, Tuple, Union
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import constraint_sharding, get_abstract_mesh
+from repro.compat import get_abstract_mesh
 
 PyTree = Any
 MeshAxes = Union[None, str, Tuple[str, ...]]
@@ -272,9 +272,7 @@ def with_logical_constraint(x: jax.Array, *axes: Optional[str]) -> jax.Array:
             kept = tuple(a for a in part if a in axis_names)
             clean.append(kept if kept else None)
     try:
-        return jax.lax.with_sharding_constraint(
-            x, constraint_sharding(mesh, P(*clean))
-        )
+        return jax.lax.with_sharding_constraint(x, P(*clean))
     except (ValueError, TypeError) as e:
         # Only the expected constraint failures (rank/axis mismatches) are
         # tolerable — and even those get ONE warning per (spec, mesh) so a

@@ -6,7 +6,17 @@ and WKV6 scans (recurrent archs, chunked-parallel TPU forms), and int8
 quantization (the compressed-allreduce building block).
 
 Models call :mod:`repro.kernels.ops`; oracles live in :mod:`repro.kernels.ref`.
+:func:`interpret_default` is the one place that decides whether a Pallas
+kernel runs in the interpreter.
 """
-from repro.kernels import ops, ref
+import jax
 
-__all__ = ["ops", "ref"]
+
+def interpret_default() -> bool:
+    """Run Pallas kernels in the interpreter: only on the CPU backend."""
+    return jax.default_backend() == "cpu"
+
+
+from repro.kernels import ops, ref  # noqa: E402  (ops imports the above)
+
+__all__ = ["interpret_default", "ops", "ref"]

@@ -3,9 +3,8 @@
 Flash-decoding adapted to TPU: one query row per (batch, head) attends to the
 KV cache in VMEM-sized chunks; running (m, l, acc) stats carried in scratch
 across the innermost grid dimension (TPU sequential grid), masked by each
-batch row's valid cache length.  The valid length arrives as a (B, 1) int32
-block in SMEM-like VMEM — no scalar prefetch needed in interpret mode and the
-layout is also legal on hardware.
+batch row's valid cache length.  The (B,) valid lengths are scalar-prefetched
+into SMEM: a (1, 1) VMEM block of a (B, 1) array is not a legal TPU tiling.
 
 q block is a single row (1, D); to keep the MXU fed the kv chunk (bk, D) is
 multiplied as (bk, D) x (D, 1) — a skinny matmul the TPU lowers to VPU+MXU
@@ -66,7 +65,7 @@ def _decode_kernel(
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    valid = valid_ref[0, 0]                                 # () int32
+    valid = valid_ref[pl.program_id(0)]                     # () int32
     first_k = ik * bk
     live = first_k < valid
 
@@ -103,7 +102,7 @@ def _decode_int8_kernel(
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    valid = valid_ref[0, 0]
+    valid = valid_ref[pl.program_id(0)]
     first_k = ik * bk
     live = first_k < valid
 
@@ -122,7 +121,8 @@ def _decode_int8_kernel(
 
 def _paged_decode_kernel(
     table_ref,                  # scalar-prefetch: (B, NP) int32 block table
-    valid_ref, q_ref, k_ref, v_ref, o_ref,
+    valid_ref,                  # scalar-prefetch: (B,) int32 valid lengths
+    q_ref, k_ref, v_ref, o_ref,
     m_ref, l_ref, acc_ref,
     *,
     scale: float,
@@ -136,7 +136,9 @@ def _paged_decode_kernel(
     (b, h, j) is DMA'd straight from page ``table[b, j]`` of the shared pool —
     the block table is scalar-prefetched so the index map can address pages
     before the body runs.  Shared prefix pages are fetched per-sequence but
-    stored once (ref-counted by the serve-side BlockAllocator)."""
+    stored once (ref-counted by the serve-side BlockAllocator).  The pool
+    arrives head-flattened, (P, page, Hkv * D), so one head's rows of a page
+    are a (page, D) block."""
     j = pl.program_id(2)
 
     @pl.when(j == 0)
@@ -145,15 +147,15 @@ def _paged_decode_kernel(
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    valid = valid_ref[0, 0]
+    valid = valid_ref[pl.program_id(0)]
     first_k = j * page_size
     live = first_k < valid
 
     @pl.when(live)
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32) * scale         # (1, D)
-        k = k_ref[0, :, 0].astype(jnp.float32)              # (page, D)
-        v = v_ref[0, :, 0].astype(jnp.float32)
+        k = k_ref[0].astype(jnp.float32)                    # (page, D)
+        v = v_ref[0].astype(jnp.float32)
         _online_update(q, k, v, first_k, valid, window, m_ref, l_ref, acc_ref)
 
     @pl.when(j == n_pages - 1)
@@ -164,16 +166,19 @@ def _paged_decode_kernel(
 
 def _paged_decode_int8_kernel(
     table_ref,                  # scalar-prefetch: (B, NP) int32 block table
-    valid_ref, q_ref, k_ref, ks_ref, v_ref, vs_ref, o_ref,
+    valid_ref,                  # scalar-prefetch: (B,) int32 valid lengths
+    q_ref, k_ref, ks_ref, v_ref, vs_ref, o_ref,
     m_ref, l_ref, acc_ref,
     *,
     scale: float,
     window: Optional[int],
     page_size: int,
     n_pages: int,
+    group: int,
 ):
     """:func:`_paged_decode_kernel` over int8 pages + per-row scale pages;
-    dequantize happens in VMEM after the page DMA."""
+    dequantize happens in VMEM after the page DMA.  A scale page holds every
+    kv head's column, (page, Hkv); the kernel picks its own with a mask."""
     j = pl.program_id(2)
 
     @pl.when(j == 0)
@@ -182,15 +187,19 @@ def _paged_decode_int8_kernel(
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    valid = valid_ref[0, 0]
+    valid = valid_ref[pl.program_id(0)]
     first_k = j * page_size
     live = first_k < valid
+    col = jax.lax.broadcasted_iota(jnp.int32, ks_ref.shape[1:], 1)
+    own = col == pl.program_id(1) // group                  # (page, Hkv)
 
     @pl.when(live)
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32) * scale         # (1, D)
-        k = k_ref[0, :, 0].astype(jnp.float32) * ks_ref[0, :, 0]  # (page, D)
-        v = v_ref[0, :, 0].astype(jnp.float32) * vs_ref[0, :, 0]
+        ks = jnp.sum(jnp.where(own, ks_ref[0], 0.0), axis=1, keepdims=True)
+        vs = jnp.sum(jnp.where(own, vs_ref[0], 0.0), axis=1, keepdims=True)
+        k = k_ref[0].astype(jnp.float32) * ks               # (page, D)
+        v = v_ref[0].astype(jnp.float32) * vs
         _online_update(q, k, v, first_k, valid, window, m_ref, l_ref, acc_ref)
 
     @pl.when(j == n_pages - 1)
@@ -223,30 +232,29 @@ def paged_decode_attention(
     group = H // Hkv
 
     qt = jnp.moveaxis(q, 2, 1)                              # (B, H, 1, D)
-    valid2 = valid_len.astype(jnp.int32).reshape(B, 1)
     table = block_table.astype(jnp.int32)
+    flat = lambda t: t.reshape(n_pool, page_size, Hkv * t.shape[3])
 
     kernel = functools.partial(
         _paged_decode_kernel,
         scale=1.0 / math.sqrt(D), window=window,
         page_size=page_size, n_pages=NP,
     )
+    page_spec = pl.BlockSpec(
+        (1, page_size, D),
+        lambda b, h, j, tbl, n, g=group: (tbl[b, j], 0, h // g),
+    )
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(B, H, NP),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda b, h, j, tbl: (b, 0)),
-            pl.BlockSpec((1, 1, 1, D), lambda b, h, j, tbl: (b, h, 0, 0)),
-            pl.BlockSpec(
-                (1, page_size, 1, D),
-                lambda b, h, j, tbl, g=group: (tbl[b, j], 0, h // g, 0),
-            ),
-            pl.BlockSpec(
-                (1, page_size, 1, D),
-                lambda b, h, j, tbl, g=group: (tbl[b, j], 0, h // g, 0),
-            ),
+            pl.BlockSpec((1, 1, 1, D), lambda b, h, j, tbl, n: (b, h, 0, 0)),
+            page_spec,
+            page_spec,
         ],
-        out_specs=pl.BlockSpec((1, 1, 1, D), lambda b, h, j, tbl: (b, h, 0, 0)),
+        out_specs=pl.BlockSpec(
+            (1, 1, 1, D), lambda b, h, j, tbl, n: (b, h, 0, 0)
+        ),
         scratch_shapes=[
             pltpu.VMEM((1, 1), jnp.float32),
             pltpu.VMEM((1, 1), jnp.float32),
@@ -258,7 +266,7 @@ def paged_decode_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype),
         interpret=interpret,
-    )(table, valid2, qt, k_pages, v_pages)
+    )(table, valid_len.astype(jnp.int32), qt, flat(k_pages), flat(v_pages))
     return jnp.moveaxis(out, 1, 2)                          # (B, 1, H, D)
 
 
@@ -280,35 +288,37 @@ def paged_decode_attention_int8(
     int8 (plus its scale column) and dequantized inside the kernel — the
     decode sweep moves ~1/4 the KV bytes of the f32 pool."""
     B, _, H, D = q.shape
-    page_size, Hkv = k_pages.shape[1], k_pages.shape[2]
+    n_pool, page_size, Hkv = k_pages.shape[0], k_pages.shape[1], k_pages.shape[2]
     NP = block_table.shape[1]
     assert H % Hkv == 0
     group = H // Hkv
 
     qt = jnp.moveaxis(q, 2, 1)                              # (B, H, 1, D)
-    valid2 = valid_len.astype(jnp.int32).reshape(B, 1)
     table = block_table.astype(jnp.int32)
+    flat = lambda t: t.reshape(n_pool, page_size, Hkv * t.shape[3])
 
     kernel = functools.partial(
         _paged_decode_int8_kernel,
         scale=1.0 / math.sqrt(D), window=window,
-        page_size=page_size, n_pages=NP,
+        page_size=page_size, n_pages=NP, group=group,
     )
-    page_spec = lambda shape: pl.BlockSpec(
-        shape, lambda b, h, j, tbl, g=group: (tbl[b, j], 0, h // g, 0)
+    page_spec = pl.BlockSpec(
+        (1, page_size, D),
+        lambda b, h, j, tbl, n, g=group: (tbl[b, j], 0, h // g),
+    )
+    scale_spec = pl.BlockSpec(
+        (1, page_size, Hkv), lambda b, h, j, tbl, n: (tbl[b, j], 0, 0)
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(B, H, NP),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda b, h, j, tbl: (b, 0)),
-            pl.BlockSpec((1, 1, 1, D), lambda b, h, j, tbl: (b, h, 0, 0)),
-            page_spec((1, page_size, 1, D)),
-            page_spec((1, page_size, 1, 1)),
-            page_spec((1, page_size, 1, D)),
-            page_spec((1, page_size, 1, 1)),
+            pl.BlockSpec((1, 1, 1, D), lambda b, h, j, tbl, n: (b, h, 0, 0)),
+            page_spec, scale_spec, page_spec, scale_spec,
         ],
-        out_specs=pl.BlockSpec((1, 1, 1, D), lambda b, h, j, tbl: (b, h, 0, 0)),
+        out_specs=pl.BlockSpec(
+            (1, 1, 1, D), lambda b, h, j, tbl, n: (b, h, 0, 0)
+        ),
         scratch_shapes=[
             pltpu.VMEM((1, 1), jnp.float32),
             pltpu.VMEM((1, 1), jnp.float32),
@@ -320,7 +330,8 @@ def paged_decode_attention_int8(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype),
         interpret=interpret,
-    )(table, valid2, qt, k_pages, k_scales, v_pages, v_scales)
+    )(table, valid_len.astype(jnp.int32), qt, flat(k_pages), flat(k_scales),
+      flat(v_pages), flat(v_scales))
     return jnp.moveaxis(out, 1, 2)                          # (B, 1, H, D)
 
 
@@ -348,30 +359,34 @@ def decode_attention(
         kt = jnp.pad(kt, ((0, 0), (0, 0), (0, pad_k), (0, 0)))
         vt = jnp.pad(vt, ((0, 0), (0, 0), (0, pad_k), (0, 0)))
     n_kv = kt.shape[2] // bk
-    valid2 = valid_len.astype(jnp.int32).reshape(B, 1)
 
-    grid = (B, H, n_kv)
     kernel = functools.partial(
         _decode_kernel, scale=1.0 / math.sqrt(D), window=window, bk=bk, n_kv=n_kv
     )
+    kv_spec = pl.BlockSpec(
+        (1, 1, bk, D), lambda b, h, ik, n, g=group: (b, h // g, ik, 0)
+    )
     out = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda b, h, ik: (b, 0)),
-            pl.BlockSpec((1, 1, 1, D), lambda b, h, ik: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, bk, D), lambda b, h, ik, g=group: (b, h // g, ik, 0)),
-            pl.BlockSpec((1, 1, bk, D), lambda b, h, ik, g=group: (b, h // g, ik, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, 1, D), lambda b, h, ik: (b, h, 0, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B, H, n_kv),
+            in_specs=[
+                pl.BlockSpec((1, 1, 1, D), lambda b, h, ik, n: (b, h, 0, 0)),
+                kv_spec, kv_spec,
+            ],
+            out_specs=pl.BlockSpec(
+                (1, 1, 1, D), lambda b, h, ik, n: (b, h, 0, 0)
+            ),
+            scratch_shapes=[
+                pltpu.VMEM((1, 1), jnp.float32),
+                pltpu.VMEM((1, 1), jnp.float32),
+                pltpu.VMEM((1, D), jnp.float32),
+            ],
+        ),
         out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((1, D), jnp.float32),
-        ],
         interpret=interpret,
-    )(valid2, qt, kt, vt)
+    )(valid_len.astype(jnp.int32), qt, kt, vt)
     return jnp.moveaxis(out, 1, 2)                # (B, 1, H, D)
 
 
@@ -406,34 +421,36 @@ def decode_attention_int8(
         pad = ((0, 0), (0, 0), (0, pad_k), (0, 0))
         kt, vt, kst, vst = (jnp.pad(t, pad) for t in (kt, vt, kst, vst))
     n_kv = kt.shape[2] // bk
-    valid2 = valid_len.astype(jnp.int32).reshape(B, 1)
 
-    grid = (B, H, n_kv)
     kernel = functools.partial(
         _decode_int8_kernel,
         scale=1.0 / math.sqrt(D), window=window, bk=bk, n_kv=n_kv,
     )
     kv_spec = lambda shape: pl.BlockSpec(
-        shape, lambda b, h, ik, g=group: (b, h // g, ik, 0)
+        shape, lambda b, h, ik, n, g=group: (b, h // g, ik, 0)
     )
     out = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda b, h, ik: (b, 0)),
-            pl.BlockSpec((1, 1, 1, D), lambda b, h, ik: (b, h, 0, 0)),
-            kv_spec((1, 1, bk, D)),
-            kv_spec((1, 1, bk, 1)),
-            kv_spec((1, 1, bk, D)),
-            kv_spec((1, 1, bk, 1)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, 1, D), lambda b, h, ik: (b, h, 0, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B, H, n_kv),
+            in_specs=[
+                pl.BlockSpec((1, 1, 1, D), lambda b, h, ik, n: (b, h, 0, 0)),
+                kv_spec((1, 1, bk, D)),
+                kv_spec((1, 1, bk, 1)),
+                kv_spec((1, 1, bk, D)),
+                kv_spec((1, 1, bk, 1)),
+            ],
+            out_specs=pl.BlockSpec(
+                (1, 1, 1, D), lambda b, h, ik, n: (b, h, 0, 0)
+            ),
+            scratch_shapes=[
+                pltpu.VMEM((1, 1), jnp.float32),
+                pltpu.VMEM((1, 1), jnp.float32),
+                pltpu.VMEM((1, D), jnp.float32),
+            ],
+        ),
         out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((1, D), jnp.float32),
-        ],
         interpret=interpret,
-    )(valid2, qt, kt, kst, vt, vst)
+    )(valid_len.astype(jnp.int32), qt, kt, kst, vt, vst)
     return jnp.moveaxis(out, 1, 2)                # (B, 1, H, D)

@@ -35,10 +35,13 @@ top-k + the stable sort that assigns capacity slots (Pallas TPU has no sort
 primitive — vLLM's fused_moe splits the same way).  Those are O(T·k) index
 ops, not the O(T·d·f) hot loop.
 
-Scaling note: this variant holds the full ``(T, d)`` activation block in
-VMEM (fine for the per-device token counts this repo runs; a production
-kernel would double-buffer token tiles from HBM).  Tests run in interpret
-mode; block shapes are MXU-aligned so the same kernel compiles on TPU.
+Scaling note: the dispatch kernel holds the full ``(T, d)`` activation block
+in VMEM, so its scoped-VMEM limit is raised to :data:`_GEMM_VMEM_LIMIT`
+(v5e has 128 MiB per core; 2048 tokens at d_model 2048 compile with 32
+experts held).  A production kernel would double-buffer token tiles from
+HBM.  The combine kernel streams the slot buffer in capacity blocks.  Tests
+run in interpret mode; ``tests/test_chip_compile.py`` compiles both kernels
+for a v5e at qwen3-moe-30b-a3b widths.
 """
 from __future__ import annotations
 
@@ -49,6 +52,12 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+# the resident (T, d) tokens are double-buffered next to three expert
+# weight blocks; the 16 MiB default cannot hold them at real widths
+_GEMM_VMEM_LIMIT = 96 << 20
+# slot rows per combine block (a divisor of the capacity)
+_COMBINE_BLOCK_S = 512
 
 
 def moe_routing(
@@ -116,24 +125,24 @@ def _fused_moe_kernel(
     bc: int,
     T: int,
 ):
-    x = x_ref[...].astype(jnp.float32)                          # (T, d)
+    x = x_ref[...]                                              # (T, d)
     idx = tok_ref[...]                                          # (bc, 1)
-    # dispatch gather as a one-hot matmul: sentinel index T matches no token
+    # dispatch gather as a one-hot matmul: sentinel index T matches no token;
+    # a 0/1 selection in the tokens' own dtype copies rows exactly
     sel = (idx == jax.lax.broadcasted_iota(jnp.int32, (bc, T), 1)
-           ).astype(jnp.float32)
+           ).astype(x.dtype)
     xs = jax.lax.dot_general(                                   # (bc, d) MXU
         sel, x, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )
-    wg = wg_ref[0].astype(jnp.float32)
-    wu = wu_ref[0].astype(jnp.float32)
-    wo = wo_ref[0].astype(jnp.float32)
-    g = jax.lax.dot_general(
-        xs, wg, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-    u = jax.lax.dot_general(
-        xs, wu, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+    # expert GEMMs in the weights' dtype with f32 accumulation
+    wdt = wg_ref.dtype
+    g = jax.lax.dot_general(xs.astype(wdt), wg_ref[0], (((1,), (0,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+    u = jax.lax.dot_general(xs.astype(wdt), wu_ref[0], (((1,), (0,)), ((), ())),
+                            preferred_element_type=jnp.float32)
     h = jax.nn.silu(g) * u
-    y = jax.lax.dot_general(
-        h, wo, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+    y = jax.lax.dot_general(h.astype(wdt), wo_ref[0], (((1,), (0,)), ((), ())),
+                            preferred_element_type=jnp.float32)
     y_ref[...] = (y * gate_ref[...]).astype(y_ref.dtype)
 
 
@@ -172,29 +181,43 @@ def fused_moe_gemm(
         ],
         out_specs=pl.BlockSpec((bc, d), lambda e, cb, n=n_cb: (e * n + cb, 0)),
         out_shape=jax.ShapeDtypeStruct((S, d), x.dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_GEMM_VMEM_LIMIT
+        ),
         interpret=interpret,
     )(slot_tok, slot_gate, x, wg, wu, wo)
 
 
 def _combine_kernel(
-    tok_ref,                    # (S, 1) int32 slot->token table
-    y_ref,                      # (S, d) gated expert outputs
+    tok_ref,                    # (bs, 1) int32 slot->token table block
+    y_ref,                      # (bs, d) gated expert output block
     o_ref,                      # (bt, d) token-row output block
+    acc_ref,                    # (bt, d) f32 scratch, summed over slot blocks
     *,
     bt: int,
-    S: int,
+    bs: int,
+    n_s: int,
 ):
-    it = pl.program_id(0)
-    tok = tok_ref[...]                                          # (S, 1)
+    it, js = pl.program_id(0), pl.program_id(1)
+
+    @pl.when(js == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    tok = tok_ref[...]                                          # (bs, 1)
     # transposed one-hot: column t of `sel` marks the slots owned by token
     # t0+t; empty slots carry the sentinel token index (>= T) and their y
     # rows are gate-zeroed anyway, so they contribute exact +0.0
-    t_iota = it * bt + jax.lax.broadcasted_iota(jnp.int32, (S, bt), 1)
-    sel = (tok == t_iota).astype(jnp.float32)                   # (S, bt)
-    y = y_ref[...].astype(jnp.float32)
-    o_ref[...] = jax.lax.dot_general(                           # (bt, d) MXU
-        sel, y, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    ).astype(o_ref.dtype)
+    t_iota = it * bt + jax.lax.broadcasted_iota(jnp.int32, (bs, bt), 1)
+    sel = (tok == t_iota).astype(y_ref.dtype)                   # (bs, bt)
+    acc_ref[...] += jax.lax.dot_general(                        # (bt, d) MXU
+        sel, y_ref[...], (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+
+    @pl.when(js == n_s - 1)
+    def _emit():
+        o_ref[...] = acc_ref[...].astype(o_ref.dtype)
 
 
 def fused_moe_combine(
@@ -202,6 +225,7 @@ def fused_moe_combine(
     slot_tok: jax.Array,        # (E*C, 1) int32 (sentinel T for empty slots)
     T: int,
     *,
+    capacity: int,
     block_t: int = 128,
     interpret: bool = False,
 ) -> jax.Array:
@@ -209,24 +233,31 @@ def fused_moe_combine(
 
     Bit-exact vs the XLA ``.at[st].add`` scatter: every token sums the same
     <= k gated slot rows, and summing them with interleaved exact zeros is
-    the same f32 value as the sequential scatter-add.
+    the same f32 value as the sequential scatter-add.  Slot blocks divide
+    the capacity, so a block lies inside one expert's slots and holds at
+    most one copy of any token: the blockwise f32 sum keeps slot order.
     """
     S, d = y.shape
-    assert slot_tok.shape == (S, 1), slot_tok.shape
+    assert slot_tok.shape == (S, 1) and S % capacity == 0, (
+        slot_tok.shape, capacity)
+    bs = next(b for b in range(min(capacity, _COMBINE_BLOCK_S), 0, -1)
+              if capacity % b == 0 and (b % 8 == 0 or b == capacity))
     bt = min(block_t, max(T, 8))
     pad_t = (-T) % bt
     Tp = T + pad_t
     # padded token rows only ever match the sentinel's gate-zeroed slots (or
     # nothing at all), and are sliced back off below
+    n_s = S // bs
     out = pl.pallas_call(
-        functools.partial(_combine_kernel, bt=bt, S=S),
-        grid=(Tp // bt,),
+        functools.partial(_combine_kernel, bt=bt, bs=bs, n_s=n_s),
+        grid=(Tp // bt, n_s),
         in_specs=[
-            pl.BlockSpec((S, 1), lambda it: (0, 0)),
-            pl.BlockSpec((S, d), lambda it: (0, 0)),
+            pl.BlockSpec((bs, 1), lambda it, js: (js, 0)),
+            pl.BlockSpec((bs, d), lambda it, js: (js, 0)),
         ],
-        out_specs=pl.BlockSpec((bt, d), lambda it: (it, 0)),
+        out_specs=pl.BlockSpec((bt, d), lambda it, js: (it, 0)),
         out_shape=jax.ShapeDtypeStruct((Tp, d), y.dtype),
+        scratch_shapes=[pltpu.VMEM((bt, d), jnp.float32)],
         interpret=interpret,
     )(slot_tok, y)
     return out[:T]
@@ -267,7 +298,8 @@ def fused_moe_mlp_fwd(
         # gates were applied in-kernel; dropped copies never got a slot and
         # empty slots are gate-zeroed, so the one-hot contraction is the
         # whole combine
-        out = fused_moe_combine(y, slot_tok, T, interpret=interpret)
+        out = fused_moe_combine(y, slot_tok, T, capacity=C,
+                                interpret=interpret)
     else:
         out = _combine_xla(y, st, slot, keep, T, E, C)
     return out, aux

@@ -1,7 +1,9 @@
 """Jit'd public wrappers for every Pallas kernel, with CPU fallbacks.
 
 The model code calls THESE (never pallas_call directly).  Each op:
-  * dispatches to the Pallas kernel (interpret=True on CPU, compiled on TPU),
+  * dispatches to the Pallas kernel — in the interpreter on the CPU backend,
+    compiled on a TPU; callers take ``interpret`` from
+    :func:`repro.kernels.interpret_default`, the one place that decides it,
   * exposes a ``use_kernel=False`` escape hatch to the jnp oracle,
   * is differentiable: forward kernels carry a ``jax.custom_vjp`` whose
     backward recomputes through the reference (flash-style recompute — the
@@ -15,6 +17,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_default  # noqa: F401  (re-exported)
 from repro.kernels import ref as R
 from repro.kernels.decode_attention import decode_attention as _decode_pallas
 from repro.kernels.decode_attention import (

@@ -16,12 +16,19 @@ PRNG) so the kernel stays deterministic per seed on every backend.
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import interpret_default
+
+
+# bytes of one f32 (rows, N) input block: x and noise, each double-buffered,
+# plus the int8 output stay inside v5e's 16 MiB default scoped VMEM
+_BLOCK_BYTES = 2 << 20
 
 
 def _quant_kernel(x_ref, noise_ref, q_ref, scale_ref):
@@ -54,8 +61,10 @@ def _quantize_rows(
     # pad-and-mask for any R: the row block is sublane-aligned (multiple of
     # 8, so ragged R also compiles on TPU), rows pad with zeros — per-row
     # scales mean padding never contaminates real rows — and the pad rows
-    # are sliced back off below.
-    br = min(block_rows, ((R + 7) // 8) * 8)
+    # are sliced back off below.  Wide rows shrink the block to the VMEM
+    # budget.
+    fit = max(8, _BLOCK_BYTES // (4 * N) // 8 * 8)
+    br = min(block_rows, fit, ((R + 7) // 8) * 8)
     pad = (-R) % br
     if pad:
         x = jnp.pad(x, ((0, pad), (0, 0)))
@@ -119,15 +128,10 @@ def _quantize_flat_jit(vec: jax.Array, chunk: int, interpret: bool):
 
 
 def quantize_flat(
-    vec: jax.Array,
-    *,
-    chunk: int = 512,
-    interpret: Optional[bool] = None,
+    vec: jax.Array, *, chunk: int = 512
 ) -> Tuple[jax.Array, jax.Array]:
     """Quantize a flat f32 vector to (q int8 (rows, chunk), scale f32 (rows, 1))."""
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
-    return _quantize_flat_jit(jnp.asarray(vec), chunk, interpret)
+    return _quantize_flat_jit(jnp.asarray(vec), chunk, interpret_default())
 
 
 def dequantize_flat(q, scale, size: int):

@@ -11,14 +11,14 @@ prefix sums  cum_t = sum_{j<=t} lw_j:
     inter-chunk:  out  = (r_t * exp(cum_{t-1})) @ S_0          (one (L,D)x(D,D) MXU matmul)
     intra-chunk:  A_{t,j} = sum_d r_t[d] k_j[d] exp(cum_{t-1,d} - cum_{j,d}),  j <  t
                   A_{t,t} = sum_d r_t[d] u[d] k_t[d]
-                  out += A @ V                                  ((L,L)x(L,D) MXU matmul)
+                  out += A @ V                                  (one key row j at a time)
     state:        S_L  = diag(exp(cum_L)) S_0 + (k * exp(cum_L - cum))^T @ V
 
 Every exponent above is <= 0 (decays only accumulate), so the chunked form is
 overflow-safe WITHOUT the unstable 1/decay factorization a naive CUDA port
-would use.  The intra-chunk pairwise decay is materialized as an (L, L, D)
-masked tensor — with L = 32, D = 64 that is 256 KB of VMEM, well inside
-budget, and the two big matmuls dominate on the MXU.  The state (D, D) is
+would use.  The intra-chunk term loops over the L key rows on 2-D (L, D)
+tiles (Mosaic can lay out neither the (L, L, D) pairwise tensor nor
+``cumsum``; the prefix sum is a triangular matmul).  The state (D, D) is
 carried across chunks in VMEM scratch (sequential innermost grid dim).
 """
 from __future__ import annotations
@@ -51,7 +51,15 @@ def _wkv6_body(
 
     s0 = s_ref[...]                           # (D, D)
 
-    cum = jnp.cumsum(lw, axis=0)              # (L, D), cum_t = sum_{j<=t}
+    # cum_t = sum_{j<=t} lw_j as a lower-triangular matmul (Mosaic has no
+    # cumsum); HIGHEST keeps the f32 sum exact enough for the decay math
+    incl = (jax.lax.broadcasted_iota(jnp.int32, (L, L), 0) >=
+            jax.lax.broadcasted_iota(jnp.int32, (L, L), 1)).astype(jnp.float32)
+    cum = jax.lax.dot_general(                # (L, D)
+        incl, lw, (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
+    )
     cum_prev = cum - lw                       # sum_{j<t}
 
     # inter-chunk: r_t scaled by accumulated decay hits the carried state
@@ -60,24 +68,29 @@ def _wkv6_body(
         q_eff, s0, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )                                         # (L, D)
 
-    # intra-chunk: pairwise decayed attention, strictly lower triangular
-    # decay[t, j, d] = exp(cum_prev[t, d] - cum[j, d])  for j < t  (<= 0 exp)
-    expo = cum_prev[:, None, :] - cum[None, :, :]         # (L, L, D)
-    tri = jax.lax.broadcasted_iota(jnp.int32, (L, L), 0) > \
-        jax.lax.broadcasted_iota(jnp.int32, (L, L), 1)
-    decay = jnp.where(tri[:, :, None], jnp.exp(jnp.minimum(expo, 0.0)), 0.0)
-    attn = jnp.einsum("td,jd,tjd->tj", r, k, decay)       # (L, L)
-    bonus = jnp.sum(r * u[None, :] * k, axis=1)           # (L,) diagonal term
-    attn = attn + jnp.diag(bonus)
-    out = out + jax.lax.dot_general(
-        attn, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )
+    # intra-chunk, one key row j at a time (2-D tiles only: Mosaic cannot
+    # lay out the (L, L, D) pairwise tensor):
+    #   A[t, j] = sum_d r_t[d] k_j[d] exp(cum_prev[t, d] - cum[j, d]), j < t
+    #   out_t  += A[t, j] v_j
+    t_idx = jax.lax.broadcasted_iota(jnp.int32, (L, 1), 0)
+    for j in range(L):
+        decay = jnp.exp(jnp.minimum(cum_prev - cum[j:j + 1], 0.0))  # <= 0 exp
+        a = jnp.sum(r * k[j:j + 1] * decay, axis=1, keepdims=True)   # (L, 1)
+        out = out + jnp.where(t_idx > j, a, 0.0) * v[j:j + 1]
+    # diagonal bonus: A[t, t] = sum_d r_t[d] u[d] k_t[d]
+    out = out + jnp.sum(r * u * k, axis=1, keepdims=True) * v
     o_ref[0, 0] = out.astype(o_ref.dtype)
 
     # state update: S_L = diag(exp(cum_L)) S0 + (k * exp(cum_L - cum))^T V
-    cum_L = cum[L - 1]                                     # (D,)
-    k_dec = k * jnp.exp(cum_L[None, :] - cum)              # exponent <= 0
-    s_new = jnp.exp(cum_L)[:, None] * s0 + jax.lax.dot_general(
+    cum_L = cum[L - 1:L]                                   # (1, D)
+    k_dec = k * jnp.exp(cum_L - cum)                       # exponent <= 0
+    # exp(cum_L) as a (D, 1) column: the diagonal of its row broadcast
+    D = s0.shape[0]
+    eye = jax.lax.broadcasted_iota(jnp.int32, (D, D), 0) == \
+        jax.lax.broadcasted_iota(jnp.int32, (D, D), 1)
+    decay_col = jnp.sum(jnp.where(eye, jnp.exp(cum_L), 0.0), axis=1,
+                        keepdims=True)
+    s_new = decay_col * s0 + jax.lax.dot_general(
         k_dec, v, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )
     s_ref[...] = s_new
@@ -151,7 +164,7 @@ def rwkv6_scan(
             pl.BlockSpec((1, 1, L, D), lambda b, h, ic: (b, h, ic, 0)),
             pl.BlockSpec((1, 1, L, D), lambda b, h, ic: (b, h, ic, 0)),
             pl.BlockSpec((1, 1, L, D), lambda b, h, ic: (b, h, ic, 0)),
-            pl.BlockSpec((1, D), lambda b, h, ic: (h, 0)),
+            pl.BlockSpec((1, 1, D), lambda b, h, ic: (h, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, L, D), lambda b, h, ic: (b, h, ic, 0)),
@@ -163,7 +176,7 @@ def rwkv6_scan(
         ],
         scratch_shapes=[pltpu.VMEM((D, D), jnp.float32)],
         interpret=interpret,
-    )(rt, kt, vt, lwt, u)
+    )(rt, kt, vt, lwt, u.reshape(H, 1, D))
     out = jnp.moveaxis(out, 1, 2)[:, :S]
     return out, s_fin
 
@@ -212,7 +225,7 @@ def rwkv6_scan_int8(
         in_specs=[
             act_spec, sc_spec, act_spec, sc_spec, act_spec, sc_spec,
             act_spec,
-            pl.BlockSpec((1, D), lambda b, h, ic: (h, 0)),
+            pl.BlockSpec((1, 1, D), lambda b, h, ic: (h, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, L, D), lambda b, h, ic: (b, h, ic, 0)),
@@ -224,6 +237,6 @@ def rwkv6_scan_int8(
         ],
         scratch_shapes=[pltpu.VMEM((D, D), jnp.float32)],
         interpret=interpret,
-    )(rt, rst, kt, kst, vt, vst, lwt, u)
+    )(rt, rst, kt, kst, vt, vst, lwt, u.reshape(H, 1, D))
     out = jnp.moveaxis(out, 1, 2)[:, :S]
     return out, s_fin

@@ -30,8 +30,8 @@ Execution strategy is ``ClusterContext.mode``:
     numerically the single-program step (dense models exactly; see
     :func:`repro.train.steps.make_partial_grad_step`).
 
-The single-process fallback is the degenerate N=1 launch: same factory,
-same session, no handshake — ``repro.compat`` keeps the code path one.
+The single-process case is the degenerate N=1 launch: same factory,
+same session, no handshake.
 
 CLI (the worker entry the coordinator spawns, also usable by hand):
 
@@ -395,33 +395,19 @@ class WorkerRuntime:
     factory_kwargs: Dict[str, Any]
     heartbeat_interval: float = 0.25
     transport: Optional[Dict[str, Any]] = None   # TransportSpec kwargs
-    compile_cache_dir: Optional[str] = None
 
     def run(self, resume_steps: int = 2) -> Dict[str, Any]:
         from repro.compat import distributed_initialize
         from repro.launch.mesh import ClusterContext
 
-        distributed = False
-        if self.num_processes > 1:
-            distributed = distributed_initialize(
-                self.coordinator, self.num_processes, self.process_id
-            )
-            if not distributed:
-                raise RuntimeError(
-                    "this runtime cannot initialize jax.distributed; launch "
-                    "with processes=1 (the repro.compat fallback) instead"
-                )
+        distributed_initialize(
+            self.coordinator, self.num_processes, self.process_id
+        )
         import jax
 
-        if self.compile_cache_dir:
-            # shared persistent XLA cache: re-launches of the same shapes
-            # (CI smokes, bench sweeps, respawned workers) skip the compile
-            try:
-                jax.config.update(
-                    "jax_compilation_cache_dir", self.compile_cache_dir
-                )
-            except Exception:
-                pass
+        from repro.launch.compile_cache import configure_compile_cache
+
+        configure_compile_cache()
 
         tspec = TransportSpec(**(self.transport or {}))
         sync = (
@@ -657,9 +643,6 @@ class ClusterCoordinator:
         self.membership_dir = (
             spec.membership_dir or os.path.join(self.run_dir, "members")
         )
-        self.compile_cache_dir = spec.compile_cache_dir or os.path.join(
-            tempfile.gettempdir(), "repro-xla-cache"
-        )
         self.coordinator_port = spec.coordinator_port or _free_port()
         self._server: Optional[SyncServer] = None
         self._procs: List[subprocess.Popen] = []
@@ -678,6 +661,9 @@ class ClusterCoordinator:
         for pid in range(n):
             env = dict(os.environ)
             if self.spec.local_devices:
+                # forced host devices: the children stay off any accelerator
+                # (one process per chip; the parent may hold it)
+                env["JAX_PLATFORMS"] = "cpu"
                 env["XLA_FLAGS"] = (
                     f"--xla_force_host_platform_device_count="
                     f"{self.spec.local_devices}"
@@ -700,7 +686,6 @@ class ClusterCoordinator:
                 "--resume-steps", str(resume_steps),
                 "--heartbeat-interval", str(self.spec.heartbeat_interval),
                 "--transport", json.dumps(self.spec.transport.to_dict()),
-                "--compile-cache-dir", self.compile_cache_dir,
             ]
             self._procs.append(subprocess.Popen(
                 cmd, env=env, stdout=out, stderr=subprocess.STDOUT,
@@ -841,7 +826,6 @@ def _worker_main(argv: Optional[Sequence[str]] = None) -> int:
     ap.add_argument("--resume-steps", type=int, default=2)
     ap.add_argument("--heartbeat-interval", type=float, default=0.25)
     ap.add_argument("--transport", default="{}")
-    ap.add_argument("--compile-cache-dir", default=None)
     args = ap.parse_args(argv)
 
     runtime = WorkerRuntime(
@@ -854,7 +838,6 @@ def _worker_main(argv: Optional[Sequence[str]] = None) -> int:
         factory_kwargs=json.loads(args.factory_kwargs),
         heartbeat_interval=args.heartbeat_interval,
         transport=json.loads(args.transport),
-        compile_cache_dir=args.compile_cache_dir,
     )
     record = runtime.run(resume_steps=args.resume_steps)
     body = json.dumps(record, indent=1)

@@ -205,9 +205,7 @@ def _lower_and_compile(cfg, shape, mesh, rules, opt_rules=None):
     p_shardings = arg_shardings_for_tree(p_axes, params, rules, mesh)
     batch = input_specs(cfg, shape)
 
-    from repro.compat import set_mesh
-
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             opt = adamw()
             step = make_train_step(model, opt, lambda s: jnp.float32(1e-3))

@@ -23,6 +23,7 @@ import jax
 
 from repro.api import ServeSession
 from repro.configs import ARCHS, get_config, smoke_config
+from repro.launch.compile_cache import configure_compile_cache
 from repro.models.api import get_model
 from repro.serve import EngineConfig, SamplingParams, run_load
 
@@ -50,6 +51,7 @@ def build_parser() -> argparse.ArgumentParser:
 
 def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
+    configure_compile_cache()
 
     cfg = get_config(args.arch) if args.full_config else smoke_config(args.arch)
     model = get_model(cfg)

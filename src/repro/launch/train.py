@@ -27,6 +27,7 @@ import jax.numpy as jnp
 from repro.api import FleetSpec, Session, SessionConfig
 from repro.configs import ARCHS, get_config, smoke_config
 from repro.core.tuner import measured_benchmark
+from repro.launch.compile_cache import configure_compile_cache
 from repro.storage import DataConfig
 from repro.models.api import get_model
 from repro.optim import adamw, sgd_momentum
@@ -121,6 +122,7 @@ def main(argv=None) -> int:
     ap.add_argument("--cluster-local-devices", type=int, default=0,
                     help="force this many (fake CPU) devices per process")
     args = ap.parse_args(argv)
+    configure_compile_cache()
 
     if args.cluster_processes > 1:
         return _run_cluster(args)

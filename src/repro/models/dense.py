@@ -53,7 +53,7 @@ def init_params(
         _init_block(stacked(b, cfg.n_layers).scope("blocks"), cfg)
         L.init_rmsnorm(b, "ln_f", cfg.d_model)
         if not cfg.tie_embeddings:
-            L.init_embedding(b, "lm_head", cfg.vocab, cfg.d_model)
+            L.init_unembedding(b, "lm_head", cfg.vocab, cfg.d_model)
 
     return build(f, key=key, abstract=abstract, dtype=dtype)
 

@@ -32,11 +32,12 @@ from repro.models.param import (
 @dataclasses.dataclass
 class ComputeFlags:
     use_pallas: bool = False          # dispatch attention/scan hot spots to kernels
-    pallas_interpret: bool = True     # CPU container: interpret mode
     attn_dtype: Any = jnp.float32     # accumulation dtype for attention softmax
     # switch to the chunked (flash-style, O(S·chunk)-memory) XLA attention path
     # when Sq*Skv exceeds this; the exact sdpa stays the small-shape oracle.
-    chunk_threshold: int = 4 * 1024 * 1024
+    # Sequences past 1024 go chunked: exact scores for 8 x 2048 tokens at 32
+    # heads are 4 GiB of f32, more than a v5e's step can spare.
+    chunk_threshold: int = 1024 * 1024
     attn_chunk: int = 512             # KV chunk length for the chunked path
     causal_block_skip: bool = False   # skip fully-masked KV chunks (block-causal)
 
@@ -287,30 +288,6 @@ def chunked_sdpa(
     l0 = jnp.zeros((B, H, Sq), FLAGS.attn_dtype)
     acc0 = jnp.zeros((B, Sq, H, D), FLAGS.attn_dtype)
 
-    def body(carry, xs):
-        m, l, acc = carry
-        kc, vc, ci = xs                                   # (B,c,H,D) x2, ()
-        k_pos = ci * chunk + jnp.arange(chunk)            # (c,)
-        logits = jnp.einsum("bqhd,bkhd->bhqk", qf, kc.astype(FLAGS.attn_dtype))
-        if softcap is not None:
-            logits = jnp.tanh(logits / softcap) * softcap
-        mask = k_pos[None, :] < Skv                       # drop right-padding
-        if causal:
-            mask = mask & (k_pos[None, :] <= q_pos[:, None])
-        if window is not None:
-            mask = mask & (k_pos[None, :] > (q_pos[:, None] - window))
-        logits = jnp.where(mask[None, None], logits, -1e30)
-        m_new = jnp.maximum(m, jnp.max(logits, axis=-1))
-        p = jnp.exp(logits - m_new[..., None])
-        # Rows with every position masked keep m=-inf -> p would be exp(0)=1.
-        p = jnp.where(mask[None, None], p, 0.0)
-        corr = jnp.exp(m - m_new)
-        corr = jnp.where(jnp.isfinite(corr), corr, 0.0)   # first-chunk -inf - -inf
-        l = l * corr + jnp.sum(p, axis=-1)
-        pv = jnp.einsum("bhqk,bkhd->bqhd", p, vc.astype(FLAGS.attn_dtype))
-        acc = acc * jnp.moveaxis(corr, 1, 2)[..., None] + pv
-        return (m, l, acc), None
-
     # carry m is updated via m_new; rebind for scan correctness
     def scan_body(carry, xs):
         m, l, acc = carry
@@ -339,7 +316,12 @@ def chunked_sdpa(
         acc2 = acc * jnp.moveaxis(corr, 1, 2)[..., None] + pv
         return (m_new, l2, acc2), None
 
-    (m, l, acc), _ = jax.lax.scan(scan_body, (m0, l0, acc0), (ks, vs, chunk_ids))
+    # checkpointed body: autodiff keeps only the (m, l, acc) carries per
+    # chunk, not each chunk's (B, H, Sq, chunk) scores, so training memory
+    # stays O(B·H·Sq·chunk) too
+    (m, l, acc), _ = jax.lax.scan(
+        jax.checkpoint(scan_body), (m0, l0, acc0), (ks, vs, chunk_ids)
+    )
     l = jnp.maximum(l, 1e-30)
     out = acc / jnp.moveaxis(l, 1, 2)[..., None]
     return out.astype(v.dtype)
@@ -355,7 +337,7 @@ def _dispatch_attention(
 
         return kops.flash_attention(
             q, k, v, causal=causal, window=window,
-            interpret=FLAGS.pallas_interpret,
+            interpret=kops.interpret_default(),
         )
     if q.shape[1] * k.shape[1] > FLAGS.chunk_threshold:
         return chunked_sdpa(q, k, v, causal=causal, window=window, softcap=softcap)
@@ -397,17 +379,10 @@ def attention_train(
 
         o = kops.flash_attention_q8(
             q, k, v, causal=causal, window=window,
-            interpret=FLAGS.pallas_interpret, use_kernel=FLAGS.use_pallas,
-        )
-    elif FLAGS.use_pallas:
-        from repro.kernels import ops as kops
-
-        o = kops.flash_attention(
-            q, k, v, causal=causal, window=window,
-            interpret=FLAGS.pallas_interpret,
+            interpret=kops.interpret_default(), use_kernel=FLAGS.use_pallas,
         )
     else:
-        o = sdpa(q, k, v, causal=causal, window=window)
+        o = _dispatch_attention(q, k, v, causal=causal, window=window)
     return out_project(p, o.astype(x.dtype))
 
 
@@ -518,7 +493,7 @@ def attention_decode(
 
             o = kops.decode_attention_int8(
                 q, ck, cks, cv, cvs, valid,
-                window=window, interpret=FLAGS.pallas_interpret,
+                window=window, interpret=kops.interpret_default(),
             )
         else:
             o = _decode_sdpa_exact(
@@ -537,7 +512,7 @@ def attention_decode(
         from repro.kernels import ops as kops
 
         o = kops.decode_attention(
-            q, ck, cv, valid, window=window, interpret=FLAGS.pallas_interpret
+            q, ck, cv, valid, window=window, interpret=kops.interpret_default()
         )
     else:
         o = _decode_sdpa_exact(q, ck, cv, valid - 1, window)
@@ -673,6 +648,13 @@ def geglu(p: Dict, x: jax.Array) -> jax.Array:
 def init_embedding(b: ParamBuilder, name: str, vocab: int, d_model: int):
     s = b.scope(name)
     s.param("table", (vocab, d_model), ("vocab", "embed"), init=normal_init(1.0))
+
+
+def init_unembedding(b: ParamBuilder, name: str, vocab: int, d_model: int):
+    """Untied output head: 1/sqrt(d_model) init keeps the initial logits at
+    unit scale, so a fresh model's loss starts at ln(vocab)."""
+    s = b.scope(name)
+    s.param("table", (vocab, d_model), ("vocab", "embed"), init=scaled_init(-1))
 
 
 def embed(p: Dict, tokens: jax.Array, dtype: Any = jnp.float32) -> jax.Array:

@@ -89,7 +89,7 @@ def _moe_mlp_fused(p: Dict, x: jax.Array, cfg: ModelConfig) -> Tuple[jax.Array, 
     out, aux = kops.fused_moe_mlp(
         x.reshape(T, d), p["router"], p["wi_gate"], p["wi_up"], p["wo"],
         k=cfg.experts_per_token, capacity=C,
-        interpret=L.FLAGS.pallas_interpret,
+        interpret=kops.interpret_default(),
     )
     out = wlc(out.reshape(B, S, d), "batch", "seq", "act_embed")
     return out, aux
@@ -318,7 +318,7 @@ def init_params(cfg: ModelConfig, key=None, abstract=False, dtype=None):
         _init_block(stacked(b, cfg.n_layers).scope("blocks"), cfg)
         L.init_rmsnorm(b, "ln_f", cfg.d_model)
         if not cfg.tie_embeddings:
-            L.init_embedding(b, "lm_head", cfg.vocab, cfg.d_model)
+            L.init_unembedding(b, "lm_head", cfg.vocab, cfg.d_model)
 
     return build(f, key=key, abstract=abstract, dtype=dtype)
 

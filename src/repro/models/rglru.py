@@ -78,7 +78,7 @@ def rglru_scan(p: Dict, x: jax.Array, h0: Optional[jax.Array] = None,
         # gated input streams as int8 + per-row scales; the decay a stays f32
         # (seq padding inside the kernel must be exactly 1.0 to pass the carry)
         y = kops.rglru_scan_q8(
-            a, gated, interpret=FLAGS.pallas_interpret,
+            a, gated, interpret=kops.interpret_default(),
             use_kernel=FLAGS.use_pallas,
         )
     elif FLAGS.use_pallas:
@@ -86,7 +86,7 @@ def rglru_scan(p: Dict, x: jax.Array, h0: Optional[jax.Array] = None,
             gated = gated.astype(jnp.bfloat16).astype(jnp.float32)
         from repro.kernels import ops as kops
 
-        y = kops.rglru_scan(a, gated, interpret=FLAGS.pallas_interpret)
+        y = kops.rglru_scan(a, gated, interpret=kops.interpret_default())
     else:
         if precision == "bf16":
             gated = gated.astype(jnp.bfloat16).astype(jnp.float32)
@@ -217,7 +217,7 @@ def init_params(cfg: ModelConfig, key=None, abstract=False, dtype=None):
             _init_layer(stacked(g, n_a).scope("A"), cfg, "A")
         L.init_rmsnorm(b, "ln_f", cfg.d_model)
         if not cfg.tie_embeddings:
-            L.init_embedding(b, "lm_head", cfg.vocab, cfg.d_model)
+            L.init_unembedding(b, "lm_head", cfg.vocab, cfg.d_model)
 
     return build(f, key=key, abstract=abstract, dtype=dtype)
 

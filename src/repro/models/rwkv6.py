@@ -169,13 +169,13 @@ def time_mix(
             # decay w stays f32 (its log-cumsum is the overflow-safety math)
             out, _s = kops.rwkv6_scan_q8(
                 r4, k4, v4, w4, u,
-                interpret=FLAGS.pallas_interpret, use_kernel=FLAGS.use_pallas,
+                interpret=kops.interpret_default(), use_kernel=FLAGS.use_pallas,
             )
         elif FLAGS.use_pallas:
             from repro.kernels import ops as kops
 
             out, _s = kops.rwkv6_scan(
-                r4, k4, v4, w4, u, interpret=FLAGS.pallas_interpret
+                r4, k4, v4, w4, u, interpret=kops.interpret_default()
             )
         else:
             out, _s = wkv6_ref(r4, k4, v4, w4, u)
@@ -245,7 +245,7 @@ def init_params(cfg: ModelConfig, key=None, abstract=False, dtype=None):
         _init_block(stacked(b, cfg.n_layers).scope("blocks"), cfg)
         L.init_layernorm(b, "ln_f", cfg.d_model)
         if not cfg.tie_embeddings:
-            L.init_embedding(b, "lm_head", cfg.vocab, cfg.d_model)
+            L.init_unembedding(b, "lm_head", cfg.vocab, cfg.d_model)
 
     return build(f, key=key, abstract=abstract, dtype=dtype)
 

@@ -39,7 +39,6 @@ from typing import Dict, Optional, Tuple
 
 import numpy as np
 
-from repro.compat import process_index as _process_index
 from repro.storage.synthetic import SyntheticDevice
 
 
@@ -241,7 +240,7 @@ class MeshFeeder:
             bytes_put=int(bytes_put),
             n_puts=int(n_puts),
             devices=tuple(sorted(dev_ids)),
-            process_index=_process_index(),
+            process_index=jax.process_index(),
         )
         self.last_local = local_out if want_local else None
         return out

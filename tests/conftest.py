@@ -7,22 +7,3 @@ import pytest
 @pytest.fixture(scope="session")
 def rng_key():
     return jax.random.PRNGKey(0)
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
-
-
-def pytest_collection_modifyitems(config, items):
-    """``@pytest.mark.tpu`` tests exercise the compiled (interpret=False)
-    Pallas kernels; everywhere else they skip instead of erroring in the
-    Mosaic lowering."""
-    if _on_tpu():
-        return
-    skip = pytest.mark.skip(reason="requires a TPU (compiled Pallas kernels)")
-    for item in items:
-        if "tpu" in item.keywords:
-            item.add_marker(skip)

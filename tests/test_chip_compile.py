@@ -1,0 +1,141 @@
+"""Main-path Pallas kernels compiled for a described TPU v5e at real widths.
+
+Nothing runs: each case lowers and compiles one kernel with ``interpret=False``
+for one chip of a ``v5e:2x2`` topology that is described, not attached.  The
+TPU compiler then refuses what interpret mode cannot see — block shapes that
+do not align with the tiling, and more fast memory (VMEM) than a kernel may
+use.  Widths:
+
+  * attention at deepseek-7b's 32 heads x 128, seq 2048, bf16;
+  * fused MoE at qwen3-moe-30b-a3b's d_model 2048, expert d_ff 768, top-8,
+    with 32 experts held (128 over 4 chips) and 2048 tokens;
+  * rwkv6 at rwkv6-7b's 64 heads x 64.
+
+The topology is described inside a fixture (never at import or collection),
+so each pytest-xdist worker collects the same tests and only the worker that
+runs this file loads the TPU library.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels import fused_moe as FM
+from repro.kernels import ops
+from repro.models.moe import expert_capacity
+
+SEQ, HEADS, HEAD_DIM = 2048, 32, 128
+DECODE_BATCH, PAGE = 8, 16
+MOE_TOKENS, MOE_D, MOE_FF, MOE_EXPERTS, MOE_K = 2048, 2048, 768, 32, 8
+MOE_CAPACITY = expert_capacity(MOE_TOKENS, MOE_EXPERTS, MOE_K, 1.25)
+RWKV_HEADS, RWKV_DIM = 64, 64
+
+bf16, f32, i32, i8 = jnp.bfloat16, jnp.float32, jnp.int32, jnp.int8
+ATT = (1, SEQ, HEADS, HEAD_DIM)
+KV = (DECODE_BATCH, SEQ, HEADS, HEAD_DIM)
+POOL = (DECODE_BATCH * SEQ // PAGE, PAGE, HEADS, HEAD_DIM)
+TABLE = (DECODE_BATCH, SEQ // PAGE)
+Q1 = (DECODE_BATCH, 1, HEADS, HEAD_DIM)
+WKV = (1, SEQ, RWKV_HEADS, RWKV_DIM)
+SLOTS = MOE_EXPERTS * MOE_CAPACITY
+
+# name -> (kernel call, argument (shape, dtype) list)
+CASES = {
+    "flash_attention": (
+        lambda q, k, v: ops.flash_attention(q, k, v, causal=True,
+                                            interpret=False),
+        [(ATT, bf16)] * 3,
+    ),
+    "flash_attention_q8": (
+        lambda q, k, v: ops.flash_attention_q8(q, k, v, causal=True,
+                                               interpret=False),
+        [(ATT, bf16)] * 3,
+    ),
+    "decode_attention": (
+        lambda q, k, v, n: ops.decode_attention(q, k, v, n, interpret=False),
+        [(Q1, bf16), (KV, bf16), (KV, bf16), ((DECODE_BATCH,), i32)],
+    ),
+    "decode_attention_int8": (
+        lambda q, k, ks, v, vs, n: ops.decode_attention_int8(
+            q, k, ks, v, vs, n, interpret=False),
+        [(Q1, bf16), (KV, i8), (KV[:3] + (1,), f32), (KV, i8),
+         (KV[:3] + (1,), f32), ((DECODE_BATCH,), i32)],
+    ),
+    "paged_decode_attention": (
+        lambda q, k, v, t, n: ops.paged_decode_attention(
+            q, k, v, t, n, interpret=False),
+        [(Q1, bf16), (POOL, bf16), (POOL, bf16), (TABLE, i32),
+         ((DECODE_BATCH,), i32)],
+    ),
+    "paged_decode_attention_int8": (
+        lambda q, k, ks, v, vs, t, n: ops.paged_decode_attention_int8(
+            q, k, ks, v, vs, t, n, interpret=False),
+        [(Q1, bf16), (POOL, i8), (POOL[:3] + (1,), f32), (POOL, i8),
+         (POOL[:3] + (1,), f32), (TABLE, i32), ((DECODE_BATCH,), i32)],
+    ),
+    "fused_moe_mlp": (
+        lambda x, r, wg, wu, wo: ops.fused_moe_mlp(
+            x, r, wg, wu, wo, k=MOE_K, capacity=MOE_CAPACITY,
+            interpret=False),
+        [((MOE_TOKENS, MOE_D), bf16), ((MOE_D, MOE_EXPERTS), bf16),
+         ((MOE_EXPERTS, MOE_D, MOE_FF), bf16),
+         ((MOE_EXPERTS, MOE_D, MOE_FF), bf16),
+         ((MOE_EXPERTS, MOE_FF, MOE_D), bf16)],
+    ),
+    "fused_moe_combine": (
+        lambda y, tok: FM.fused_moe_combine(
+            y, tok, MOE_TOKENS, capacity=MOE_CAPACITY, interpret=False),
+        [((SLOTS, MOE_D), bf16), ((SLOTS, 1), i32)],
+    ),
+    "rwkv6_scan": (
+        lambda r, k, v, w, u: ops.rwkv6_scan(r, k, v, w, u, interpret=False),
+        [(WKV, bf16)] * 4 + [((RWKV_HEADS, RWKV_DIM), f32)],
+    ),
+    "rwkv6_scan_q8": (
+        lambda r, k, v, w, u: ops.rwkv6_scan_q8(r, k, v, w, u,
+                                                interpret=False),
+        [(WKV, bf16)] * 4 + [((RWKV_HEADS, RWKV_DIM), f32)],
+    ),
+    "quantize_int8": (
+        lambda x, noise: ops.quantize_int8(x, noise, interpret=False),
+        [((4096, 4096), f32)] * 2,
+    ),
+}
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    """A compile for a described chip cannot be read back from the cache."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.mark.parametrize("name", sorted(CASES))
+def test_kernel_compiles_for_v5e(name, one_chip, no_persistent_cache):
+    fn, specs = CASES[name]
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+            for shape, dtype in specs]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()

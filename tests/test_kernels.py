@@ -228,6 +228,12 @@ def test_paged_decode_attention_int8_matches_oracle(case):
 # ---------------------------------------------------------------------------
 
 
+def test_interpret_default_follows_the_backend(monkeypatch):
+    assert ops.interpret_default() is (jax.default_backend() == "cpu")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert ops.interpret_default() is False
+
+
 def _moe_inputs(key, T, d, f, E):
     ks = jax.random.split(key, 5)
     x = jax.random.normal(ks[0], (T, d), jnp.float32)
@@ -643,7 +649,7 @@ def _combine_case(seed, T=64, d=32, E=8, k=2, C=8):
     router = jax.random.normal(ks[1], (d, E)) * 0.5
     slot_tok, _gate, st, slot, keep, _aux = FM.moe_routing(x, router, k, C)
     y = jax.random.normal(ks[2], (E * C, d), jnp.float32)
-    got = FM.fused_moe_combine(y, slot_tok, T, interpret=True)
+    got = FM.fused_moe_combine(y, slot_tok, T, capacity=C, interpret=True)
     want = FM._combine_xla(y, st, slot, keep, T, E, C)
     assert bool(jnp.all(got == want)), f"combine not bit-exact (seed {seed})"
 

@@ -28,7 +28,7 @@ def _run(code: str, n: int = 4) -> str:
 def test_local_dispatch_matches_dense_forward_and_grad():
     out = _run("""
         import jax, jax.numpy as jnp
-        from repro.compat import make_mesh, set_mesh
+        from repro.compat import make_mesh
         from repro.configs import smoke_config
         from repro.models import moe
         from repro.models.api import get_model
@@ -54,7 +54,7 @@ def test_local_dispatch_matches_dense_forward_and_grad():
 
         mesh = make_mesh((2, 2), ('data', 'model'))
         moe.MOE_IMPL = 'auto'
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             out, aux = jax.jit(lambda p, t: m.forward(p, t))(params, batch['tokens'])
             _, _, m2 = jax.jit(step)(params, opt.init(params), batch)
 
@@ -78,7 +78,7 @@ def test_local_dispatch_matches_dense_under_capacity_overflow():
     so which copies drop — and hence the output — must match exactly."""
     out = _run("""
         import jax, jax.numpy as jnp
-        from repro.compat import make_mesh, set_mesh
+        from repro.compat import make_mesh
         from repro.configs import smoke_config
         from repro.models import moe
         from repro.models.api import get_model
@@ -101,7 +101,7 @@ def test_local_dispatch_matches_dense_under_capacity_overflow():
 
         mesh = make_mesh((1, 4), ('data', 'model'))
         moe.MOE_IMPL = 'auto'
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             out, aux = jax.jit(lambda p, t: m.forward(p, t))(params, tokens)
         ferr = float(jnp.max(jnp.abs(out - ref)))
         aerr = float(jnp.abs(aux - aux_ref))
@@ -117,7 +117,7 @@ def test_local_dispatch_over_model_batch_layout():
     all-gather + psum_scatter path must also match."""
     out = _run("""
         import jax, jax.numpy as jnp
-        from repro.compat import make_mesh, set_mesh
+        from repro.compat import make_mesh
         from repro.configs import smoke_config
         from repro.distributed.sharding import make_rules, set_rules
         from repro.models import moe
@@ -136,7 +136,7 @@ def test_local_dispatch_over_model_batch_layout():
         rules = make_rules(extra={'batch': ('pod', 'data', 'model')})
         set_rules(rules)
         moe.MOE_IMPL = 'auto'
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             out, _ = jax.jit(lambda p, t: m.forward(p, t))(params, tokens)
         set_rules(make_rules())
         err = float(jnp.max(jnp.abs(out - ref)))

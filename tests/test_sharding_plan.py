@@ -273,7 +273,6 @@ def test_use_rules_installs_and_restores():
 
 
 def test_constraint_mismatch_warns_once():
-    from repro.compat import set_mesh
     from repro.distributed.sharding import reset_constraint_warnings
     from repro.launch.mesh import make_single_mesh
 
@@ -282,7 +281,7 @@ def test_constraint_mismatch_warns_once():
     reset_constraint_warnings()
     mesh = make_single_mesh()
     x = jnp.zeros((4,))
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         with warnings.catch_warnings(record=True) as w:
             warnings.simplefilter("always")
             # rank-mismatched constraint (2 sharded parts on a 1-D array):
@@ -293,7 +292,7 @@ def test_constraint_mismatch_warns_once():
             with_logical_constraint(x, "batch", "heads")
             assert len([r for r in w if r.category is RuntimeWarning]) == 1
     # a well-formed constraint still applies silently
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         with warnings.catch_warnings(record=True) as w:
             warnings.simplefilter("always")
             with_logical_constraint(jnp.zeros((4, 4)), "batch", None)
