@@ -78,6 +78,12 @@ class TrainReport:
     ``opt_state`` lets a caller continue training seamlessly after an
     elastic event: ``session.run(report.params, opt_state=report.opt_state)``
     keeps optimizer moments and the lr-schedule step counter.
+
+    Each ``history`` entry holds the step's metrics, ``step_time`` (dispatch
+    to metrics on the host), and the host seconds of the loop's spans:
+    ``feed_s``, ``dispatch_s``, ``readback_s`` and ``control_s``.
+    ``readbacks`` counts the step metrics the call read back to the host, each
+    a blocking device-to-host read.
     """
 
     params: PyTree
@@ -87,6 +93,7 @@ class TrainReport:
     start_step: int
     compile_count: int
     wall_time: float
+    readbacks: int
 
     @property
     def final_loss(self) -> float:

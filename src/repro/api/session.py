@@ -70,6 +70,7 @@ from repro.api.artifacts import (
 from repro.api.callbacks import CallbackRegistry
 from repro.api.events import DriftDetected, FleetEvent, WorkerJoined, WorkerLost
 from repro.api.fleet import FleetSpec
+from repro.api.spans import Span
 from repro.checkpoint.manager import CheckpointManager, ClusterCheckpointManager
 from repro.core.hetero import BatchSchedule, schedule_from_tune
 from repro.core.load_balance import EpochPlan, plan_epoch
@@ -658,20 +659,13 @@ class Session:
 
     # -- stage 5: training ------------------------------------------------
 
-    def run(
-        self,
-        params: Optional[PyTree] = None,
-        *,
-        opt_state: Optional[PyTree] = None,
-        steps: Optional[int] = None,
-    ) -> TrainReport:
-        """Train.  Pass a prior report's ``params`` AND ``opt_state`` to
-        continue after an elastic event — the optimizer's moments and the
-        lr-schedule step counter live in ``opt_state``, so omitting it
-        restarts warmup from step 0."""
+    def _prepare_run(
+        self, params: Optional[PyTree], opt_state: Optional[PyTree]
+    ) -> Tuple[CompiledStep, Any, int, PyTree, PyTree]:
+        """What ``run`` does before its first step: the compiled step, the
+        checkpoint manager, and the state to start from (restored, fresh,
+        or the caller's re-homed onto the live plan)."""
         cfg = self.config
-        steps = steps or cfg.total_steps
-
         compiled = self.compile()
         plan = self._exec_plan()
         ckpt = None
@@ -726,52 +720,85 @@ class Session:
                 )(params)
             else:
                 opt_state = self._adopt_state(opt_state, plan.opt)
+        return compiled, ckpt, start_step, params, opt_state
 
-        dataset = self.dataset
-        monitor = DriftMonitor(
-            margin=cfg.retune_margin, patience=cfg.retune_patience
-        )
+    def run(
+        self,
+        params: Optional[PyTree] = None,
+        *,
+        opt_state: Optional[PyTree] = None,
+        steps: Optional[int] = None,
+    ) -> TrainReport:
+        """Train.  Pass a prior report's ``params`` AND ``opt_state`` to
+        continue after an elastic event — the optimizer's moments and the
+        lr-schedule step counter live in ``opt_state``, so omitting it
+        restarts warmup from step 0."""
+        cfg = self.config
+        steps = steps or cfg.total_steps
+        with Span("train.prepare"):
+            compiled, ckpt, start_step, params, opt_state = (
+                self._prepare_run(params, opt_state)
+            )
+            dataset = self.dataset
+            monitor = DriftMonitor(
+                margin=cfg.retune_margin, patience=cfg.retune_patience
+            )
         history: List[Dict[str, float]] = []
+        readbacks = 0
         t0 = time.perf_counter()
 
         for i in range(start_step, steps):
             # batches come THROUGH the device fleet: each dp-group's rows are
             # assembled in its storage device, and the meshfeed backend lands
             # them pre-sharded on the mesh
-            batch = dataset.next_device_batch()
+            with Span("train.feed") as feed:
+                batch = dataset.next_device_batch()
             ts = time.perf_counter()
-            params, opt_state, metrics = compiled.step_fn(params, opt_state, batch)
-            metrics = {k: float(v) for k, v in metrics.items()}
+            with Span("train.dispatch") as dispatch:
+                params, opt_state, metrics = compiled.step_fn(
+                    params, opt_state, batch
+                )
+            with Span("train.readback") as readback:
+                metrics = {k: float(v) for k, v in metrics.items()}
+            readbacks += len(metrics)
             metrics["step_time"] = time.perf_counter() - ts
-            history.append(metrics)
-            self.callbacks.emit_step(i, metrics)
+            metrics["feed_s"] = feed.seconds
+            metrics["dispatch_s"] = dispatch.seconds
+            metrics["readback_s"] = readback.seconds
+            with Span("train.control") as control:
+                history.append(metrics)
+                self.callbacks.emit_step(i, metrics)
 
-            # straggler watch: feed per-class analytic times perturbed by the
-            # observed wall time (single-host stand-in for per-worker probes)
-            tp = self.tune()
-            class_times = {
-                c.name: self.fleet.by_name(c.name).step_time(
-                    tp.result.batches[c.name]
-                )
-                for c in self.fleet.classes
-                if c.name in tp.result.batches
-            }
-            if monitor.update(class_times):
-                self.apply(DriftDetected(source="monitor"))
-                compiled = self.compile()   # same object unless shapes grew
-                dataset = self.dataset
+                # straggler watch: feed per-class analytic times perturbed by
+                # the observed wall time (single-host stand-in for per-worker
+                # probes)
+                tp = self.tune()
+                class_times = {
+                    c.name: self.fleet.by_name(c.name).step_time(
+                        tp.result.batches[c.name]
+                    )
+                    for c in self.fleet.classes
+                    if c.name in tp.result.batches
+                }
+                if monitor.update(class_times):
+                    self.apply(DriftDetected(source="monitor"))
+                    compiled = self.compile()   # same object unless shapes grew
+                    dataset = self.dataset
 
-            if ckpt is not None and (i + 1) % cfg.checkpoint_every == 0:
-                ckpt.save(
-                    i + 1, {"params": params, "opt": opt_state},
-                    metadata={
-                        "step": i + 1,
-                        "schedule": list(self.tune().schedule.group_batches),
-                        "cursors": dataset.cursors(),
-                    },
-                    async_=cfg.async_checkpoint,
-                )
-                self.callbacks.emit_checkpoint(i + 1, cfg.checkpoint_dir)
+                if ckpt is not None and (i + 1) % cfg.checkpoint_every == 0:
+                    ckpt.save(
+                        i + 1, {"params": params, "opt": opt_state},
+                        metadata={
+                            "step": i + 1,
+                            "schedule": list(
+                                self.tune().schedule.group_batches
+                            ),
+                            "cursors": dataset.cursors(),
+                        },
+                        async_=cfg.async_checkpoint,
+                    )
+                    self.callbacks.emit_checkpoint(i + 1, cfg.checkpoint_dir)
+            metrics["control_s"] = control.seconds
         if ckpt is not None:
             ckpt.wait()
         return TrainReport(
@@ -782,6 +809,7 @@ class Session:
             start_step=start_step,
             compile_count=self._compile_count,
             wall_time=time.perf_counter() - t0,
+            readbacks=readbacks,
         )
 
     # -- the ONE elastic replanning path ----------------------------------

@@ -228,10 +228,3 @@ class ClusterContext:
             )
         grid = np.array(devs[:d]).reshape(d, 1)
         return Mesh(grid, ("data", "model"))
-
-
-# Hardware constants (TPU v5e-class) used by the roofline analysis.
-PEAK_FLOPS_BF16 = 197e12        # per chip
-HBM_BW = 819e9                  # bytes/s per chip
-ICI_BW = 50e9                   # bytes/s per link (effective)
-HBM_BYTES = 16 * 1024 ** 3      # per chip

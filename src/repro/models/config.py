@@ -79,7 +79,7 @@ class ModelConfig:
     def with_(self, **kw) -> "ModelConfig":
         return dataclasses.replace(self, **kw)
 
-    # -- analytic parameter counts (for roofline MODEL_FLOPS = 6·N·D) --------
+    # -- analytic parameter counts --------------------------------------------
     def param_count(self, active_only: bool = False) -> int:
         d, ff, V = self.d_model, self.d_ff, self.vocab
         hd = self.resolved_head_dim()

@@ -65,17 +65,21 @@ def init_params(
 
 def _block_train(lp: Dict, x: jax.Array, cfg: ModelConfig, positions: jax.Array,
                  mrope_positions=None) -> jax.Array:
-    h = L.rms_norm(lp["ln1"], x)
-    h = L.attention_train(
-        lp["attn"], h, positions=positions, causal=True, window=cfg.window,
-        rope_theta=cfg.rope_theta,
-        mrope_sections=cfg.mrope_sections or None,
-        mrope_positions=mrope_positions,
-        precision=cfg.train_precision,
-    )
-    x = x + h
-    h = L.rms_norm(lp["ln2"], x)
-    return x + L.mlp_apply(lp["mlp"], h, cfg.mlp)
+    # the scopes name the ops (backward and rematerialized ones too) in the
+    # compiled step, so a device trace attributes its time to a layer
+    with jax.named_scope("attention"):
+        h = L.rms_norm(lp["ln1"], x)
+        h = L.attention_train(
+            lp["attn"], h, positions=positions, causal=True, window=cfg.window,
+            rope_theta=cfg.rope_theta,
+            mrope_sections=cfg.mrope_sections or None,
+            mrope_positions=mrope_positions,
+            precision=cfg.train_precision,
+        )
+        x = x + h
+    with jax.named_scope("mlp"):
+        h = L.rms_norm(lp["ln2"], x)
+        return x + L.mlp_apply(lp["mlp"], h, cfg.mlp)
 
 
 def _scan_blocks(params: PyTree, x: jax.Array, cfg: ModelConfig, body) -> jax.Array:
@@ -96,12 +100,13 @@ def _scan_blocks(params: PyTree, x: jax.Array, cfg: ModelConfig, body) -> jax.Ar
 
 
 def _final(params: PyTree, x: jax.Array, cfg: ModelConfig) -> jax.Array:
-    x = L.rms_norm(params["ln_f"], x)
-    head = params["embedding"] if cfg.tie_embeddings else params["lm_head"]
-    y = L.logits(head, x)
-    if cfg.logit_softcap:
-        y = jnp.tanh(y / cfg.logit_softcap) * cfg.logit_softcap
-    return y
+    with jax.named_scope("vocab"):
+        x = L.rms_norm(params["ln_f"], x)
+        head = params["embedding"] if cfg.tie_embeddings else params["lm_head"]
+        y = L.logits(head, x)
+        if cfg.logit_softcap:
+            y = jnp.tanh(y / cfg.logit_softcap) * cfg.logit_softcap
+        return y
 
 
 # ---------------------------------------------------------------------------
@@ -121,7 +126,8 @@ def forward(
     if inputs_embeds is not None:
         x = inputs_embeds.astype(cfg.dtype)
     else:
-        x = L.embed(params["embedding"], tokens, cfg.dtype)
+        with jax.named_scope("vocab"):
+            x = L.embed(params["embedding"], tokens, cfg.dtype)
     S = x.shape[1]
     positions = jnp.arange(S)
     body = partial(

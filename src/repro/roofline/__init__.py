@@ -1,4 +1,3 @@
 from repro.roofline.collectives import collective_bytes_from_hlo
-from repro.roofline.analysis import roofline_terms, RooflineReport
 
-__all__ = ["collective_bytes_from_hlo", "roofline_terms", "RooflineReport"]
+__all__ = ["collective_bytes_from_hlo"]

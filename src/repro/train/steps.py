@@ -67,8 +67,9 @@ def loss_fn(
     mask = batch["loss_mask"]
     if logits.shape[1] != labels.shape[1]:
         logits = logits[:, -labels.shape[1]:]
-    ce = cross_entropy(logits, labels)
-    loss = masked_mean_loss(ce, mask)
+    with jax.named_scope("vocab"):
+        ce = cross_entropy(logits, labels)
+        loss = masked_mean_loss(ce, mask)
     total = loss + aux_weight * aux
     return total, {"loss": loss, "aux": aux, "tokens": jnp.sum(mask)}
 
@@ -93,15 +94,17 @@ def make_train_step(
         )(params)
         if grad_transform is not None:
             grads = grad_transform(grads)
-        lr = lr_schedule(opt_state.step)
-        opt_state, params = optimizer.update(grads, opt_state, params, lr)
-        # NOTE: elementwise square + sum, NOT vdot — vdot reshapes each leaf
-        # to 1-D, which GSPMD can only partition by all-gathering the whole
-        # (f32-upcast) tensor; measured at +4.5 GB/layer on qwen3-moe.
-        gnorm = jnp.sqrt(
-            sum(jnp.sum(jnp.square(g.astype(jnp.float32)))
-                for g in jax.tree_util.tree_leaves(grads))
-        )
+        with jax.named_scope("optimizer"):
+            lr = lr_schedule(opt_state.step)
+            opt_state, params = optimizer.update(grads, opt_state, params, lr)
+            # NOTE: elementwise square + sum, NOT vdot — vdot reshapes each
+            # leaf to 1-D, which GSPMD can only partition by all-gathering
+            # the whole (f32-upcast) tensor; measured at +4.5 GB/layer on
+            # qwen3-moe.
+            gnorm = jnp.sqrt(
+                sum(jnp.sum(jnp.square(g.astype(jnp.float32)))
+                    for g in jax.tree_util.tree_leaves(grads))
+            )
         metrics = {
             "loss": parts["loss"],
             "aux": parts["aux"],
