@@ -56,6 +56,9 @@ class CompiledStep:
     to assert that a drift re-tune did NOT trigger a rebuild.
     ``in_shardings``/``out_shardings`` record the explicit ShardingPlan trees
     the step was jitted with (``None`` only for externally built steps).
+    ``attention_paths`` counts, per path, the attention layers of the step's
+    latest trace (``{"splash": 2}``: two layers on the TPU kernel pair); it
+    is filled when the step is first called.
     """
 
     step_fn: Callable
@@ -66,6 +69,7 @@ class CompiledStep:
     config_key: Tuple = ()    # the SessionConfig values baked into the step
     in_shardings: Any = None  # (params, opt, batch) NamedSharding trees
     out_shardings: Any = None
+    attention_paths: Dict[str, int] = dataclasses.field(default_factory=dict)
 
     def signature(self) -> Tuple[int, int]:
         return (self.global_rows, self.seq_len)
@@ -83,7 +87,8 @@ class TrainReport:
     to metrics on the host), and the host seconds of the loop's spans:
     ``feed_s``, ``dispatch_s``, ``readback_s`` and ``control_s``.
     ``readbacks`` counts the step metrics the call read back to the host, each
-    a blocking device-to-host read.
+    a blocking device-to-host read.  ``attention_paths`` is the compiled
+    step's tally of attention layers by path (:class:`CompiledStep`).
     """
 
     params: PyTree
@@ -94,6 +99,7 @@ class TrainReport:
     compile_count: int
     wall_time: float
     readbacks: int
+    attention_paths: Dict[str, int] = dataclasses.field(default_factory=dict)
 
     @property
     def final_loss(self) -> float:

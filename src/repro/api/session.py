@@ -83,6 +83,7 @@ from repro.storage import (
     make_fleet_batcher, manifest_sources,
 )
 from repro.distributed.sharding import use_rules
+from repro.models.layers import attention_path_tally
 from repro.launch.mesh import ClusterContext, make_single_mesh
 from repro.optim.optimizers import Optimizer
 from repro.optim.schedules import goyal_schedule
@@ -106,6 +107,17 @@ _DOWNSTREAM = {
     "shard": ("compile",),
     "compile": (),
 }
+
+
+def _tallying(fn: Callable, paths: Dict[str, int]) -> Callable:
+    """``fn``, leaving in ``paths`` the attention paths its latest trace took."""
+    def traced(*args):
+        with attention_path_tally() as tally:
+            out = fn(*args)
+        paths.clear()
+        paths.update(tally)
+        return out
+    return traced
 
 
 @dataclasses.dataclass
@@ -484,8 +496,9 @@ class Session:
                 warmup_steps=self.config.warmup_steps,
                 total_steps=self.config.total_steps,
             )
+            paths: Dict[str, int] = {}
             if self._is_cluster() and self._cluster.mode == "hostsync":
-                step_fn, in_sh, out_sh = self._compile_hostsync(sched)
+                step_fn, in_sh, out_sh = self._compile_hostsync(sched, paths)
             else:
                 step = make_train_step(
                     self.model, self.optimizer, sched,
@@ -500,6 +513,8 @@ class Session:
                     # produced the argument shardings — not the defaults
                     with use_rules(plan.rules), use_abstract_mesh(mesh.abstract_mesh):
                         return step(params, opt_state, batch)
+
+                step_in_mesh = _tallying(step_in_mesh, paths)
 
                 in_sh = (plan.params, plan.opt, plan.batch)
                 # metrics are scalars: plan.replicated is a pytree-prefix
@@ -521,6 +536,7 @@ class Session:
                 config_key=self._config_key(),
                 in_shardings=in_sh,
                 out_shardings=out_sh,
+                attention_paths=paths,
             )
         return self._artifacts["compile"]
 
@@ -535,7 +551,7 @@ class Session:
             return self.fleet.cluster.transport
         return TransportSpec()
 
-    def _compile_hostsync(self, sched):
+    def _compile_hostsync(self, sched, paths: Dict[str, int]):
         """The cluster step for backends that cannot run cross-process XLA
         programs: a jitted partial-gradient half over this process's local
         plan emitting per-bucket flat f32 vectors, a
@@ -564,6 +580,8 @@ class Session:
         def grad_in_mesh(params, batch):
             with use_rules(lp.rules), use_abstract_mesh(lp.mesh.abstract_mesh):
                 return grad_step(params, batch)
+
+        grad_in_mesh = _tallying(grad_in_mesh, paths)
 
         def apply_in_mesh(params, opt_state, bucket_vecs, sums):
             with use_rules(lp.rules), use_abstract_mesh(lp.mesh.abstract_mesh):
@@ -810,6 +828,7 @@ class Session:
             compile_count=self._compile_count,
             wall_time=time.perf_counter() - t0,
             readbacks=readbacks,
+            attention_paths=dict(compiled.attention_paths),
         )
 
     # -- the ONE elastic replanning path ----------------------------------

@@ -252,18 +252,13 @@ def use_rules(rules: Dict[str, MeshAxes], constrain: bool = True):
         _CURRENT_RULES, _CONSTRAIN = prev_rules, prev_constrain
 
 
-def with_logical_constraint(x: jax.Array, *axes: Optional[str]) -> jax.Array:
-    """``with_sharding_constraint`` by logical axis names; no-op outside a mesh."""
-    if not _CONSTRAIN:
-        return x
-    mesh = get_abstract_mesh()
-    if mesh is None:
-        return x
+def mesh_spec(mesh, *axes: Optional[str]) -> P:
+    """The PartitionSpec that logical ``axes`` take on ``mesh`` under the
+    current rule table, naming only the mesh axes ``mesh`` has (a smaller
+    mesh drops the others)."""
     axis_names = set(mesh.axis_names)
-    spec = spec_for(tuple(axes), _CURRENT_RULES)
-    # Drop references to mesh axes that don't exist in the current (small) mesh.
     clean = []
-    for part in spec:
+    for part in spec_for(tuple(axes), _CURRENT_RULES):
         if part is None:
             clean.append(None)
         elif isinstance(part, str):
@@ -271,13 +266,24 @@ def with_logical_constraint(x: jax.Array, *axes: Optional[str]) -> jax.Array:
         else:
             kept = tuple(a for a in part if a in axis_names)
             clean.append(kept if kept else None)
+    return P(*clean)
+
+
+def with_logical_constraint(x: jax.Array, *axes: Optional[str]) -> jax.Array:
+    """``with_sharding_constraint`` by logical axis names; no-op outside a mesh."""
+    if not _CONSTRAIN:
+        return x
+    mesh = get_abstract_mesh()
+    if mesh is None:
+        return x
+    spec = mesh_spec(mesh, *axes)
     try:
-        return jax.lax.with_sharding_constraint(x, P(*clean))
+        return jax.lax.with_sharding_constraint(x, spec)
     except (ValueError, TypeError) as e:
         # Only the expected constraint failures (rank/axis mismatches) are
         # tolerable — and even those get ONE warning per (spec, mesh) so a
         # rule-table typo can't silently replicate a tensor forever.
-        _warn_constraint_skipped(tuple(axes), clean, mesh, e)
+        _warn_constraint_skipped(tuple(axes), spec, mesh, e)
         return x
 
 

@@ -14,9 +14,13 @@ TPU-native design (not a CUDA port):
   * Causal/local-window masking is done by block skip (pl.when over the whole
     block) + within-block iota masks, so fully-masked blocks cost no FLOPs.
 
-Backward runs through the same reference einsums via a custom_vjp residual
-recompute (standard flash recompute strategy) — on CPU it falls back to the
-pure-jnp oracle, keeping training differentiable everywhere.
+The backward (``repro.kernels.ops._flash_bwd``) differentiates the oracle
+``flash_attention_ref``, which materialises the full S x S f32 scores: a
+correctness oracle, not a training path at long sequences.  Training
+attention on a TPU (causal, no window) takes the splash forward and dq/dkv
+backward kernels bundled with JAX instead
+(``repro.kernels.ops.splash_causal_attention``, routed by
+``repro.models.layers.splash_route``).
 """
 from __future__ import annotations
 

@@ -90,6 +90,64 @@ def flash_attention(
 
 
 # ---------------------------------------------------------------------------
+# causal training attention: the splash kernel pair bundled with JAX
+# ---------------------------------------------------------------------------
+
+SPLASH_BLOCKS = (1024, 512, 256, 128)   # largest first; one 128-lane tile is the floor
+
+
+def _splash_block(seq: int, head_dim: int) -> int:
+    """The splash tile for a sequence length (a multiple of the floor): the
+    largest of :data:`SPLASH_BLOCKS` that divides it, at most
+    1024 x 128 / head_dim (a tile's VMEM grows with the head)."""
+    cap = 1024 * 128 // head_dim
+    return next(b for b in SPLASH_BLOCKS if b <= cap and seq % b == 0)
+
+
+def _splash_block_sizes(seq: int, head_dim: int):
+    """Forward: square tiles of :func:`_splash_block`, scores computed 512
+    keys at a time.  Backward: one fused dq/dkv kernel over 512 queries x a
+    whole tile of keys (on a v5e at seq 2048, D 128 this beat separate dq
+    and dkv kernels and every 256/512 square tiling; PERF.md)."""
+    from jax.experimental.pallas.ops.tpu import splash_attention as SA
+
+    b = _splash_block(seq, head_dim)
+    c = min(b, 512 * 128 // head_dim)
+    return SA.BlockSizes(
+        block_q=b, block_kv=b, block_kv_compute=c,
+        block_q_dkv=c, block_kv_dkv=b, block_kv_dkv_compute=c,
+        use_fused_bwd_kernel=True,
+    )
+
+
+def splash_causal_attention(
+    q: jax.Array, k: jax.Array, v: jax.Array, *, interpret: bool = False,
+) -> jax.Array:
+    """Causal attention through the splash forward and dq/dkv backward
+    kernels. q: (B, S, H, D); k, v: (B, S, Hkv, D) with H % Hkv == 0, S a
+    multiple of 128.
+
+    q is pre-scaled by 1/sqrt(D) and rounded to its own dtype; the kernels
+    take the operands in that dtype and keep softmax statistics and
+    accumulators in f32.  Tiles above the diagonal are skipped (no FLOPs, no
+    DMA) forward and backward.  Grouped kv heads are read in place by each
+    group of query heads (kv head = query head // (H / Hkv)); nothing is
+    repeated in memory.
+    """
+    from jax.experimental.pallas.ops.tpu import splash_attention as SA
+
+    _, S, H, D = q.shape
+    kernel = SA.make_splash_mha(
+        SA.MultiHeadMask([SA.CausalMask((S, S))] * H),
+        block_sizes=_splash_block_sizes(S, D),
+        head_shards=1, q_seq_shards=1, interpret=interpret,
+    )
+    q = (q.astype(jnp.float32) * (1.0 / D ** 0.5)).astype(q.dtype)
+    heads_major = lambda t: jnp.swapaxes(t, 1, 2)     # (B, S, H, D) <-> (B, H, S, D)
+    return heads_major(jax.vmap(kernel)(*map(heads_major, (q, k, v))))
+
+
+# ---------------------------------------------------------------------------
 # quantized-training (q8) ops: int8 streamed activations, int8 residuals
 # ---------------------------------------------------------------------------
 #

@@ -49,6 +49,13 @@ def flash_attention_ref(
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v.astype(jnp.float32)).astype(q.dtype)
 
 
+def splash_causal_attention_ref(
+    q: jax.Array, k: jax.Array, v: jax.Array,
+) -> jax.Array:
+    """Oracle of the splash training pair: exact causal attention, f32."""
+    return flash_attention_ref(q, k, v, causal=True)
+
+
 def decode_attention_ref(
     q: jax.Array,               # (B, 1, H, D)
     k: jax.Array,               # (B, Skv, Hkv, D)  (cache)

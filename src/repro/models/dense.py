@@ -89,7 +89,8 @@ def _scan_blocks(params: PyTree, x: jax.Array, cfg: ModelConfig, body) -> jax.Ar
         def step(carry, lp):
             return fn(lp, carry), None
 
-        x, _ = jax.lax.scan(step, x, blocks)
+        with L.repeated_layers(cfg.n_layers):
+            x, _ = jax.lax.scan(step, x, blocks)
     else:
         # unrolled: used by smoke tests and the dry-run's cost calibration
         # (XLA cost_analysis counts a scan body ONCE; unrolled HLO counts all)

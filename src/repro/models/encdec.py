@@ -88,7 +88,8 @@ def encode(params, cfg: ModelConfig, frames: jax.Array) -> jax.Array:
 
     fn = jax.checkpoint(body) if cfg.remat else body
     if cfg.scan_layers:
-        x, _ = jax.lax.scan(lambda c, lp: (fn(lp, c), None), x, params["enc_blocks"])
+        with L.repeated_layers(cfg.n_enc_layers):
+            x, _ = jax.lax.scan(lambda c, lp: (fn(lp, c), None), x, params["enc_blocks"])
     else:
         for i in range(cfg.n_enc_layers):
             lp = jax.tree_util.tree_map(lambda a: a[i], params["enc_blocks"])
@@ -127,7 +128,8 @@ def decode_train(params, cfg: ModelConfig, tokens: jax.Array,
 
     fn = jax.checkpoint(body) if cfg.remat else body
     if cfg.scan_layers:
-        x, _ = jax.lax.scan(lambda c, lp: (fn(lp, c), None), x, params["dec_blocks"])
+        with L.repeated_layers(cfg.n_dec_layers):
+            x, _ = jax.lax.scan(lambda c, lp: (fn(lp, c), None), x, params["dec_blocks"])
     else:
         for i in range(cfg.n_dec_layers):
             lp = jax.tree_util.tree_map(lambda a: a[i], params["dec_blocks"])

@@ -7,6 +7,7 @@ names (see :mod:`repro.distributed.sharding`).
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import math
 from functools import partial
@@ -15,6 +16,8 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.compat import get_abstract_mesh
+from repro.distributed.sharding import mesh_spec
 from repro.distributed.sharding import with_logical_constraint as wlc
 from repro.models.param import (
     ParamBuilder,
@@ -33,13 +36,14 @@ from repro.models.param import (
 class ComputeFlags:
     use_pallas: bool = False          # dispatch attention/scan hot spots to kernels
     attn_dtype: Any = jnp.float32     # accumulation dtype for attention softmax
-    # switch to the chunked (flash-style, O(S·chunk)-memory) XLA attention path
-    # when Sq*Skv exceeds this; the exact sdpa stays the small-shape oracle.
-    # Sequences past 1024 go chunked: exact scores for 8 x 2048 tokens at 32
-    # heads are 4 GiB of f32, more than a v5e's step can spare.
+    # off the TPU training route (other backends, non-causal or windowed
+    # attention, prefill): switch to the chunked (flash-style,
+    # O(S·chunk)-memory) XLA attention path when Sq*Skv exceeds this; the
+    # exact sdpa stays the small-shape oracle.  Sequences past 1024 go
+    # chunked: exact scores for 8 x 2048 tokens at 32 heads are 4 GiB of f32,
+    # more than a v5e's step can spare.
     chunk_threshold: int = 1024 * 1024
     attn_chunk: int = 512             # KV chunk length for the chunked path
-    causal_block_skip: bool = False   # skip fully-masked KV chunks (block-causal)
 
 
 FLAGS = ComputeFlags()
@@ -257,10 +261,11 @@ def chunked_sdpa(
 ) -> jax.Array:
     """Flash-style online-softmax attention over KV chunks (pure XLA).
 
-    Memory is O(B·H·Sq·chunk) instead of O(B·H·Sq·Skv) — this is the deployable
-    large-context path on which the dry-run/roofline numbers are based; the Pallas
-    kernel in :mod:`repro.kernels.flash_attention` is the TPU-native hot path.
-    Numerically matches :func:`sdpa` (property-tested).
+    Memory is O(B·H·Sq·chunk) instead of O(B·H·Sq·Skv).  The long-sequence
+    path off the TPU training route (:func:`splash_route`): other backends,
+    non-causal and windowed attention, and prefill.  Every chunk is computed
+    for every query (no causal skip) on f32 scores.  Numerically matches
+    :func:`sdpa` (property-tested).
     """
     B, Sq, H, D = q.shape
     Skv = k.shape[1]
@@ -327,21 +332,130 @@ def chunked_sdpa(
     return out.astype(v.dtype)
 
 
+def _dispatch_path(q: jax.Array, k: jax.Array) -> str:
+    """Pallas flash / chunked / exact attention, by flags and problem size."""
+    if FLAGS.use_pallas:
+        return "flash"
+    if q.shape[1] * k.shape[1] > FLAGS.chunk_threshold:
+        return "chunked"
+    return "exact"
+
+
 def _dispatch_attention(
     q: jax.Array, k: jax.Array, v: jax.Array, *, causal: bool,
     window: Optional[int], softcap: Optional[float] = None,
 ) -> jax.Array:
-    """Pick pallas / chunked / exact attention by flags and problem size."""
-    if FLAGS.use_pallas:
+    path = _dispatch_path(q, k)
+    if path == "flash":
         from repro.kernels import ops as kops
 
         return kops.flash_attention(
             q, k, v, causal=causal, window=window,
             interpret=kops.interpret_default(),
         )
-    if q.shape[1] * k.shape[1] > FLAGS.chunk_threshold:
+    if path == "chunked":
         return chunked_sdpa(q, k, v, causal=causal, window=window, softcap=softcap)
     return sdpa(q, k, v, causal=causal, window=window, softcap=softcap)
+
+
+# ---------------------------------------------------------------------------
+# Attention — the TPU training route (splash forward + dq/dkv backward)
+# ---------------------------------------------------------------------------
+
+
+def _splash_specs(mesh):
+    """q and k/v specs that split the kernel's rows over the mesh axes the
+    rules give ``batch`` and its heads over those of ``act_heads``."""
+    return (mesh_spec(mesh, "batch", "seq", "act_heads", None),
+            mesh_spec(mesh, "batch", "seq", "act_kv_heads", None))
+
+
+def _splash_fits_mesh(q_shape, k_shape) -> bool:
+    """No mesh, or one that splits neither sequence and divides the rows and
+    heads evenly."""
+    mesh = get_abstract_mesh()
+    if mesh is None:
+        return True
+    for spec, shape in zip(_splash_specs(mesh), (q_shape, k_shape)):
+        parts = tuple(spec) + (None,) * (len(shape) - len(spec))
+        if parts[1] is not None:
+            return False
+        for part, n in zip(parts, shape):
+            axes = () if part is None else (part,) if isinstance(part, str) else part
+            if n % math.prod(mesh.shape[a] for a in axes):
+                return False
+    return True
+
+
+def splash_route(
+    backend: str, q_shape, k_shape, *, causal: bool, window: Optional[int],
+    softcap: Optional[float] = None,
+) -> bool:
+    """Whether training attention runs the fused splash kernel pair: on a TPU,
+    causal, no window or softcap, Sq == Skv a multiple of the smallest splash
+    tile, whole 128-lane heads, query heads a multiple of kv heads, and rows
+    and heads dividing evenly over the mesh."""
+    from repro.kernels.ops import SPLASH_BLOCKS
+
+    _, s, h, d = q_shape
+    return (
+        backend == "tpu" and causal and window is None and softcap is None
+        and k_shape[1] == s and s % SPLASH_BLOCKS[-1] == 0
+        and d % 128 == 0 and h % k_shape[2] == 0
+        and _splash_fits_mesh(q_shape, k_shape)
+    )
+
+
+def splash_attention(q: jax.Array, k: jax.Array, v: jax.Array) -> jax.Array:
+    """Causal attention through :func:`repro.kernels.ops.splash_causal_attention`,
+    one call per chip under ``shard_map`` (XLA cannot partition the kernel),
+    so each chip runs its own rows and heads and nothing is gathered."""
+    from repro.kernels import ops as kops
+
+    core = partial(kops.splash_causal_attention,
+                   interpret=kops.interpret_default())
+    mesh = get_abstract_mesh()
+    if mesh is None:
+        return core(q, k, v)
+    q_spec, kv_spec = _splash_specs(mesh)
+    return jax.shard_map(
+        core, mesh=mesh, in_specs=(q_spec, kv_spec, kv_spec),
+        out_specs=q_spec, check_vma=False,
+    )(q, k, v)
+
+
+# Trace-time tally of the path each attention_train call takes ("splash",
+# "chunked", "exact", "flash", "q8"); Session exposes it as attention_paths.
+_PATH_TALLIES: list = []
+_LAYER_REPEATS = [1]
+
+
+@contextlib.contextmanager
+def attention_path_tally():
+    """Count, into the yielded dict, the paths of attention_train calls
+    traced inside (a scanned layer stack counts once per layer)."""
+    tally: Dict[str, int] = {}
+    _PATH_TALLIES.append(tally)
+    try:
+        yield tally
+    finally:
+        _PATH_TALLIES.remove(tally)
+
+
+@contextlib.contextmanager
+def repeated_layers(n: int):
+    """Each attention_train call traced inside stands for ``n`` layers: the
+    body of a ``lax.scan`` over a stack of ``n`` layers is traced once."""
+    _LAYER_REPEATS.append(_LAYER_REPEATS[-1] * n)
+    try:
+        yield
+    finally:
+        _LAYER_REPEATS.pop()
+
+
+def _record_path(path: str) -> None:
+    for tally in _PATH_TALLIES:
+        tally[path] = tally.get(path, 0) + _LAYER_REPEATS[-1]
 
 
 def attention_train(
@@ -364,6 +478,10 @@ def attention_train(
     quantized-K/V kernel whose backward saves int8 residuals.  The precision
     semantics hold on AND off Pallas (the q8 op has an exact jnp fallback),
     so a trajectory trained on CPU matches the TPU quantization decisions.
+    Otherwise, on a TPU, causal attention over whole tiles takes the splash
+    kernel pair (:func:`splash_route`); everything else keeps
+    :func:`_dispatch_attention`.  Each call adds its path to the active
+    :func:`attention_path_tally`.
     """
     q, k, v = qkv_project(p, x)
     if mrope_sections is not None:
@@ -377,11 +495,17 @@ def attention_train(
     if precision == "int8-fused":
         from repro.kernels import ops as kops
 
+        _record_path("q8")
         o = kops.flash_attention_q8(
             q, k, v, causal=causal, window=window,
             interpret=kops.interpret_default(), use_kernel=FLAGS.use_pallas,
         )
+    elif splash_route(jax.default_backend(), q.shape, k.shape,
+                      causal=causal, window=window):
+        _record_path("splash")
+        o = splash_attention(q, k, v)
     else:
+        _record_path(_dispatch_path(q, k))
         o = _dispatch_attention(q, k, v, causal=causal, window=window)
     return out_project(p, o.astype(x.dtype))
 

@@ -350,7 +350,9 @@ def forward(params, cfg: ModelConfig, tokens, **_) -> Tuple[jax.Array, jax.Array
             h, a = fn(lp, h)
             return (h, aux + a), None
 
-        (x, aux), _ = jax.lax.scan(step, (x, jnp.zeros((), jnp.float32)), params["blocks"])
+        with L.repeated_layers(cfg.n_layers):
+            (x, aux), _ = jax.lax.scan(
+                step, (x, jnp.zeros((), jnp.float32)), params["blocks"])
     else:
         aux = jnp.zeros((), jnp.float32)
         for i in range(cfg.n_layers):

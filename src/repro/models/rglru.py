@@ -266,7 +266,8 @@ def forward(params, cfg: ModelConfig, tokens, **_) -> jax.Array:
         fn = jax.checkpoint(lambda lp, h, _k=kind: _layer_train(lp, h, cfg, _k, positions)) \
             if cfg.remat else (lambda lp, h, _k=kind: _layer_train(lp, h, cfg, _k, positions))
         if cfg.scan_layers:
-            x, _ = jax.lax.scan(lambda c, lp: (fn(lp, c), None), x, run)
+            with L.repeated_layers(n_run):
+                x, _ = jax.lax.scan(lambda c, lp: (fn(lp, c), None), x, run)
         else:
             for li in range(n_run):
                 lp = jax.tree_util.tree_map(lambda a: a[li], run)

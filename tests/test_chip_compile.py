@@ -6,7 +6,9 @@ TPU compiler then refuses what interpret mode cannot see — block shapes that
 do not align with the tiling, and more fast memory (VMEM) than a kernel may
 use.  Widths:
 
-  * attention at deepseek-7b's 32 heads x 128, seq 2048, bf16;
+  * attention at deepseek-7b's 32 heads x 128, seq 2048, bf16; the
+    training pair (splash forward, and its dq/dkv backward under
+    ``jax.grad``) also at deepseek-coder-33b's 56 query heads over 8 kv heads;
   * fused MoE at qwen3-moe-30b-a3b's d_model 2048, expert d_ff 768, top-8,
     with 32 experts held (128 over 4 chips) and 2048 tokens;
   * rwkv6 at rwkv6-7b's 64 heads x 64.
@@ -27,6 +29,7 @@ from repro.kernels import ops
 from repro.models.moe import expert_capacity
 
 SEQ, HEADS, HEAD_DIM = 2048, 32, 128
+GQA_HEADS, GQA_KV_HEADS = 56, 8
 DECODE_BATCH, PAGE = 8, 16
 MOE_TOKENS, MOE_D, MOE_FF, MOE_EXPERTS, MOE_K = 2048, 2048, 768, 32, 8
 MOE_CAPACITY = expert_capacity(MOE_TOKENS, MOE_EXPERTS, MOE_K, 1.25)
@@ -34,6 +37,8 @@ RWKV_HEADS, RWKV_DIM = 64, 64
 
 bf16, f32, i32, i8 = jnp.bfloat16, jnp.float32, jnp.int32, jnp.int8
 ATT = (1, SEQ, HEADS, HEAD_DIM)
+GQA_Q = (1, SEQ, GQA_HEADS, HEAD_DIM)
+GQA_KV = (1, SEQ, GQA_KV_HEADS, HEAD_DIM)
 KV = (DECODE_BATCH, SEQ, HEADS, HEAD_DIM)
 POOL = (DECODE_BATCH * SEQ // PAGE, PAGE, HEADS, HEAD_DIM)
 TABLE = (DECODE_BATCH, SEQ // PAGE)
@@ -41,8 +46,23 @@ Q1 = (DECODE_BATCH, 1, HEADS, HEAD_DIM)
 WKV = (1, SEQ, RWKV_HEADS, RWKV_DIM)
 SLOTS = MOE_EXPERTS * MOE_CAPACITY
 
+def _splash(q, k, v):
+    return ops.splash_causal_attention(q, k, v, interpret=False)
+
+
+def _splash_grad(q, k, v):
+    return jax.grad(
+        lambda q, k, v: jnp.sum(_splash(q, k, v).astype(f32)),
+        argnums=(0, 1, 2))(q, k, v)
+
+
 # name -> (kernel call, argument (shape, dtype) list)
 CASES = {
+    "splash_attention": (_splash, [(ATT, bf16)] * 3),
+    "splash_attention_grad": (_splash_grad, [(ATT, bf16)] * 3),
+    "splash_attention_gqa": (_splash, [(GQA_Q, bf16)] + [(GQA_KV, bf16)] * 2),
+    "splash_attention_gqa_grad": (
+        _splash_grad, [(GQA_Q, bf16)] + [(GQA_KV, bf16)] * 2),
     "flash_attention": (
         lambda q, k, v: ops.flash_attention(q, k, v, causal=True,
                                             interpret=False),

@@ -91,3 +91,99 @@ def test_masked_softmax_rows_fully_masked_are_zero():
     v = jax.random.normal(ks[2], (1, 4, 1, 8))
     out = L.chunked_sdpa(q, k, v, causal=True, window=1, q_offset=0, chunk=2)
     assert bool(jnp.all(jnp.isfinite(out)))
+
+
+# ---------------------------------------------------------------------------
+# the TPU training route: splash forward + dq/dkv backward (interpret mode)
+# ---------------------------------------------------------------------------
+
+
+def _rel(a, b):
+    a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+    return float(np.linalg.norm(a - b) / np.linalg.norm(b))
+
+
+# name -> (splash_route keyword changes, expected); the base case takes it
+ROUTE_CASES = {
+    "tpu-causal-aligned": ({}, True),
+    "cpu-backend": ({"backend": "cpu"}, False),
+    "non-causal": ({"causal": False}, False),
+    "windowed": ({"window": 128}, False),
+    "softcap": ({"softcap": 30.0}, False),
+    "seq-not-a-tile": ({"q_shape": (2, 200, 8, 128), "k_shape": (2, 200, 2, 128)}, False),
+    "cross-lengths": ({"k_shape": (2, 1024, 2, 128)}, False),
+    "head-dim-64": ({"q_shape": (2, 512, 8, 64), "k_shape": (2, 512, 2, 64)}, False),
+    "heads-not-grouped": ({"k_shape": (2, 512, 3, 128)}, False),
+}
+
+
+@pytest.mark.parametrize("case", sorted(ROUTE_CASES))
+def test_splash_route_predicate(case):
+    change, expected = ROUTE_CASES[case]
+    args = dict(backend="tpu", q_shape=(2, 512, 8, 128),
+                k_shape=(2, 512, 2, 128), causal=True, window=None,
+                softcap=None)
+    args.update(change)
+    backend, q_shape, k_shape = (args.pop(a) for a in
+                                 ("backend", "q_shape", "k_shape"))
+    assert L.splash_route(backend, q_shape, k_shape, **args) is expected
+
+
+def _attn_params(d_model, heads, kv_heads, head_dim=128):
+    from repro.models.param import build
+
+    params, _ = build(
+        lambda b: L.init_attention(b, "attn", d_model, heads, kv_heads, head_dim),
+        key=KEY, dtype=jnp.bfloat16)
+    return params["attn"]
+
+
+def _on_tpu_route(monkeypatch):
+    """Make the backend read "tpu" to the route, with kernels interpreted."""
+    from repro.kernels import ops
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(ops, "interpret_default", lambda: True)
+
+
+def test_attention_train_takes_splash_on_tpu(monkeypatch):
+    """attention_train on the TPU route: one tallied splash call whose result
+    matches the CPU path's (chunked over f32 scores) to bf16 rounding."""
+    p = _attn_params(256, 4, 2)
+    x = jax.random.normal(KEY, (1, 1536, 256), jnp.bfloat16)
+    pos = jnp.arange(x.shape[1])
+    fn = jax.jit(lambda p, x: L.attention_train(p, x, positions=pos))
+    with L.attention_path_tally() as cpu_paths:
+        cpu = fn(p, x)
+    _on_tpu_route(monkeypatch)
+    with L.attention_path_tally() as tpu_paths:
+        tpu = jax.jit(lambda p, x: L.attention_train(p, x, positions=pos))(p, x)
+    assert cpu_paths == {"chunked": 1} and tpu_paths == {"splash": 1}
+    assert _rel(tpu, cpu) < 2e-2
+
+
+# name -> the call, which must not take the splash route on a TPU
+BYPASS_CASES = {
+    "non-causal": lambda p, x, pos: L.attention_train(p, x, positions=pos,
+                                                      causal=False),
+    "windowed": lambda p, x, pos: L.attention_train(p, x, positions=pos,
+                                                    window=64),
+    "int8-fused": lambda p, x, pos: L.attention_train(
+        p, x, positions=pos, precision="int8-fused"),
+    "prefill": lambda p, x, pos: L.attention_prefill(
+        p, x, positions=pos, cache_len=x.shape[1])[0],
+}
+
+
+@pytest.mark.parametrize("case", sorted(BYPASS_CASES))
+def test_tpu_route_leaves_other_attention_bit_identical(case, monkeypatch):
+    p = _attn_params(128, 2, 2)
+    x = jax.random.normal(KEY, (1, 256, 128), jnp.bfloat16)
+    pos = jnp.arange(x.shape[1])
+    call = BYPASS_CASES[case]
+    before = jax.jit(lambda p, x: call(p, x, pos))(p, x)
+    _on_tpu_route(monkeypatch)
+    with L.attention_path_tally() as paths:
+        after = jax.jit(lambda p, x: call(p, x, pos))(p, x)
+    assert "splash" not in paths
+    np.testing.assert_array_equal(np.asarray(after), np.asarray(before))

@@ -362,3 +362,18 @@ def test_worker_lost_manifest_reflects_quarantine():
     # no assignment may reference the dead worker or its shard
     assert all(a.worker != "csd/1" for a in m.assignments)
     assert all(a.shard_id != "private-csd/1" for a in m.assignments)
+
+
+def test_attention_paths_count_layers_on_cpu(monkeypatch):
+    """The compiled step and the report count each layer's attention path:
+    on the CPU every layer of a scanned stack takes the chunked XLA path
+    once the sequence passes the chunk threshold."""
+    from repro.models import layers as L
+
+    monkeypatch.setattr(L.FLAGS, "chunk_threshold", 32 * 32)
+    s = _session(steps=1, seq_len=48)
+    rep = s.run()
+    n_layers = s.model.cfg.n_layers
+    assert s.model.cfg.scan_layers and n_layers > 1
+    assert rep.attention_paths == {"chunked": n_layers}
+    assert s.compile().attention_paths == {"chunked": n_layers}
