@@ -70,7 +70,7 @@ from repro.api.artifacts import (
 from repro.api.callbacks import CallbackRegistry
 from repro.api.events import DriftDetected, FleetEvent, WorkerJoined, WorkerLost
 from repro.api.fleet import FleetSpec
-from repro.api.spans import Span
+from repro.api.spans import Span, count
 from repro.checkpoint.manager import CheckpointManager, ClusterCheckpointManager
 from repro.core.hetero import BatchSchedule, schedule_from_tune
 from repro.core.load_balance import EpochPlan, plan_epoch
@@ -132,7 +132,6 @@ class SessionConfig:
     base_lr: float = 1e-3
     base_batch: int = 256
     warmup_steps: int = 20
-    aux_weight: float = 0.01
     checkpoint_dir: Optional[str] = None
     checkpoint_every: int = 50
     keep_checkpoints: int = 3
@@ -475,7 +474,7 @@ class Session:
         """The SessionConfig values baked into the compiled step."""
         cfg = self.config
         return (cfg.base_lr, cfg.base_batch, cfg.warmup_steps,
-                cfg.total_steps, cfg.aux_weight)
+                cfg.total_steps)
 
     def compile(self, *, force: bool = False) -> CompiledStep:
         if force:
@@ -500,10 +499,7 @@ class Session:
             if self._is_cluster() and self._cluster.mode == "hostsync":
                 step_fn, in_sh, out_sh = self._compile_hostsync(sched, paths)
             else:
-                step = make_train_step(
-                    self.model, self.optimizer, sched,
-                    aux_weight=self.config.aux_weight,
-                )
+                step = make_train_step(self.model, self.optimizer, sched)
                 mesh = plan.mesh
 
                 def step_in_mesh(params, opt_state, batch):
@@ -569,12 +565,10 @@ class Session:
         tspec = self._transport_spec()
         params_abs, _ = self.model.init_params(abstract=True)
         groups = plan_buckets(params_abs, tspec.buckets)
-        grad_step = make_bucketed_grad_step(
-            self.model, groups, aux_weight=self.config.aux_weight
-        )
+        grad_step = make_bucketed_grad_step(self.model, groups)
         apply_step = make_bucketed_apply_step(
             self.optimizer, sched, params_abs, groups,
-            aux_weight=self.config.aux_weight,
+            aux_weight=self.model.cfg.router_aux_weight,
         )
 
         def grad_in_mesh(params, batch):
@@ -777,8 +771,15 @@ class Session:
                     params, opt_state, batch
                 )
             with Span("train.readback") as readback:
+                # an MoE step's expert rows: one read more, into the
+                # process-wide counters
+                rows = metrics.pop("moe_rows", None)
                 metrics = {k: float(v) for k, v in metrics.items()}
-            readbacks += len(metrics)
+                if rows is not None:
+                    routed, computed = np.asarray(rows).tolist()
+                    count("moe_rows_routed", routed)
+                    count("moe_rows_computed", computed)
+            readbacks += len(metrics) + (rows is not None)
             metrics["step_time"] = time.perf_counter() - ts
             metrics["feed_s"] = feed.seconds
             metrics["dispatch_s"] = dispatch.seconds

@@ -1,4 +1,4 @@
-"""Host spans of the training loop.
+"""Host spans and counters of the training loop.
 
 ``Span(name)`` is a context manager that does two things at once: it
 enters ``jax.profiler.TraceAnnotation(name)``, so that a profiler trace
@@ -10,12 +10,27 @@ the annotation costs about a microsecond.
     with Span("train.feed") as feed:
         batch = dataset.next_device_batch()
     history_entry["feed_s"] = feed.seconds
+
+``count(name, n)`` adds ``n`` to a process-wide counter and ``counters()``
+returns a copy of them all: totals over every step the process ran, for
+readers that take ratios (``Session.run`` adds each MoE step's expert rows).
 """
 from __future__ import annotations
 
 import time
+from typing import Dict
 
 import jax
+
+_COUNTERS: Dict[str, int] = {}
+
+
+def count(name: str, n: int) -> None:
+    _COUNTERS[name] = _COUNTERS.get(name, 0) + int(n)
+
+
+def counters() -> Dict[str, int]:
+    return dict(_COUNTERS)
 
 
 class Span:

@@ -145,6 +145,22 @@ def paged_decode_attention_int8_ref(
 # ---------------------------------------------------------------------------
 
 
+def grouped_matmul_ref(
+    lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array,
+) -> jax.Array:
+    """Row r of ``lhs`` times ``rhs[g]`` for the group g whose consecutive
+    rows hold r (``group_sizes`` rows each, from row 0); rows past the last
+    group are zero.  f32 products, output in lhs's dtype."""
+    ends = jnp.cumsum(group_sizes)
+    row = jnp.arange(lhs.shape[0])
+    group = jnp.searchsorted(ends, row, side="right")       # (M,)
+    out = jnp.zeros((lhs.shape[0], rhs.shape[-1]), jnp.float32)
+    for g in range(rhs.shape[0]):
+        y = lhs.astype(jnp.float32) @ rhs[g].astype(jnp.float32)
+        out = jnp.where((group == g)[:, None], y, out)
+    return out.astype(lhs.dtype)
+
+
 def fused_moe_mlp_ref(
     x: jax.Array,               # (T, d) tokens
     router: jax.Array,          # (d, E)

@@ -47,12 +47,19 @@ class Model:
 
     # -- compute --------------------------------------------------------------
     def forward(self, params, tokens, **inputs) -> Tuple[jax.Array, jax.Array]:
-        out = self.mod.forward(params, self.cfg, tokens, **inputs)
-        if isinstance(out, tuple):
-            return out
-        import jax.numpy as jnp
+        logits, aux, _ = self.forward_with_stats(params, tokens, **inputs)
+        return logits, aux
 
-        return out, jnp.zeros((), jnp.float32)
+    def forward_with_stats(self, params, tokens, **inputs) -> Tuple[
+            jax.Array, jax.Array, Dict[str, jax.Array]]:
+        """-> (logits, aux_loss, stats): ``stats`` holds the counters a
+        family's step reports (the dropless MoE's ``moe_rows``), else {}."""
+        out = self.mod.forward(params, self.cfg, tokens, **inputs)
+        if not isinstance(out, tuple):
+            import jax.numpy as jnp
+
+            return out, jnp.zeros((), jnp.float32), {}
+        return out if len(out) == 3 else (*out, {})
 
     def decode_step(self, params, token, cache, pos):
         return self.mod.decode_step(params, self.cfg, token, cache, pos)

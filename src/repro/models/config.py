@@ -23,15 +23,25 @@ class ModelConfig:
     vocab: int = 1024
     head_dim: Optional[int] = None   # default: d_model // n_heads
     qkv_bias: bool = False
+    # RMSNorm over head_dim on each query and key head before RoPE (Qwen3);
+    # its two scales exist only when on
+    qk_norm: bool = False
     rope_theta: float = 10000.0
     norm: str = "rmsnorm"            # rmsnorm | layernorm
     mlp: str = "swiglu"              # swiglu | gelu | geglu
     tie_embeddings: bool = False
 
-    # MoE
+    # MoE.  ``n_experts`` is the router's width; this chip holds
+    # ``experts_held`` of them (None: all), from ``first_expert`` on.
+    # ``capacity_factor`` None is dropless routing (every token copy is
+    # computed); a factor keeps the capacity-buffer paths, which drop overflow
     n_experts: int = 0
     experts_per_token: int = 0
-    capacity_factor: float = 1.25
+    experts_held: Optional[int] = None
+    first_expert: int = 0
+    capacity_factor: Optional[float] = 1.25
+    # weight of the summed per-layer Switch load-balancing loss in the
+    # training objective
     router_aux_weight: float = 0.01
     # fused Pallas dispatch+expert-GEMM kernel for the single-program path
     # (the group-local EP path takes precedence under a >1 "model" mesh)
@@ -76,6 +86,9 @@ class ModelConfig:
     def resolved_head_dim(self) -> int:
         return self.head_dim if self.head_dim is not None else self.d_model // self.n_heads
 
+    def resolved_experts_held(self) -> int:
+        return self.n_experts if self.experts_held is None else self.experts_held
+
     def with_(self, **kw) -> "ModelConfig":
         return dataclasses.replace(self, **kw)
 
@@ -84,9 +97,12 @@ class ModelConfig:
         d, ff, V = self.d_model, self.d_ff, self.vocab
         hd = self.resolved_head_dim()
         attn = d * hd * (self.n_heads + 2 * self.n_kv_heads) + self.n_heads * hd * d
+        if self.qk_norm:
+            attn += 2 * hd
 
         if self.family == "moe":
-            e = self.experts_per_token if active_only else self.n_experts
+            e = (self.experts_per_token if active_only
+                 else self.resolved_experts_held())
             mlp_p = 3 * d * ff * e + d * self.n_experts  # experts + router
             per_layer = attn + mlp_p
             n = self.n_layers * per_layer

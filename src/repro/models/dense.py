@@ -34,7 +34,8 @@ def _init_block(s, cfg: ModelConfig):
     hd = cfg.resolved_head_dim()
     L.init_rmsnorm(s, "ln1", cfg.d_model)
     L.init_attention(
-        s, "attn", cfg.d_model, cfg.n_heads, cfg.n_kv_heads, hd, qkv_bias=cfg.qkv_bias
+        s, "attn", cfg.d_model, cfg.n_heads, cfg.n_kv_heads, hd,
+        qkv_bias=cfg.qkv_bias, qk_norm=cfg.qk_norm,
     )
     L.init_rmsnorm(s, "ln2", cfg.d_model)
     L.init_mlp(s, "mlp", cfg.mlp, cfg.d_model, cfg.d_ff)

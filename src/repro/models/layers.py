@@ -152,6 +152,7 @@ def init_attention(
     n_kv_heads: int,
     head_dim: int,
     qkv_bias: bool = False,
+    qk_norm: bool = False,
 ):
     s = b.scope(name)
     s.param("wq", (d_model, n_heads, head_dim), ("embed", "heads", "head_dim"),
@@ -166,11 +167,16 @@ def init_attention(
         s.param("bq", (n_heads, head_dim), ("heads", "head_dim"), init=zeros_init())
         s.param("bk", (n_kv_heads, head_dim), ("kv_heads", "head_dim"), init=zeros_init())
         s.param("bv", (n_kv_heads, head_dim), ("kv_heads", "head_dim"), init=zeros_init())
+    if qk_norm:
+        init_rmsnorm(s, "q_norm", head_dim)
+        init_rmsnorm(s, "k_norm", head_dim)
 
 
 def qkv_project(
     p: Dict, x: jax.Array
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """q, k, v heads of ``x``; with QK-norm params, q and k are RMS-normed
+    per head over head_dim (one scale shared by the heads), before RoPE."""
     q = jnp.einsum("bsd,dhk->bshk", x, p["wq"].astype(x.dtype))
     k = jnp.einsum("bsd,dhk->bshk", x, p["wk"].astype(x.dtype))
     v = jnp.einsum("bsd,dhk->bshk", x, p["wv"].astype(x.dtype))
@@ -178,6 +184,9 @@ def qkv_project(
         q = q + p["bq"].astype(x.dtype)
         k = k + p["bk"].astype(x.dtype)
         v = v + p["bv"].astype(x.dtype)
+    if "q_norm" in p:
+        q = rms_norm(p["q_norm"], q)
+        k = rms_norm(p["k_norm"], k)
     q = wlc(q, "batch", "seq", "act_heads", None)
     k = wlc(k, "batch", "seq", "act_kv_heads", None)
     v = wlc(v, "batch", "seq", "act_kv_heads", None)

@@ -1,14 +1,19 @@
 """Mixture-of-Experts decoder LM (dbrx-132b: 16e top-4; qwen3-moe-30b-a3b: 128e top-8).
 
-Expert parallelism: expert weights carry the ``experts`` logical axis which the
-sharding rules map onto the ``model`` mesh axis.  Token dispatch uses the
-sort-by-expert + capacity layout (MaxText/GShard style, but with gather/scatter
-instead of one-hot einsum so memory is O(E·C·d) not O(T·E·C)); under pjit the
-scatter from token-sharded activations into expert-sharded buffers lowers to an
-all-to-all.
+Two expert layers, chosen by the config.  Dropless (``capacity_factor``
+None, qwen3-moe): the router spans all ``n_experts``, this chip holds
+``experts_held`` of them, and their token copies run through one grouped
+matmul with per-expert row counts (:func:`_moe_mlp_grouped`).  Capacity
+(dbrx): expert weights carry the ``experts`` logical axis which the sharding
+rules map onto the ``model`` mesh axis, and token dispatch uses the
+sort-by-expert + capacity layout (MaxText/GShard style, but with
+gather/scatter instead of one-hot einsum so memory is O(E·C·d) not
+O(T·E·C)); under pjit the scatter from token-sharded activations into
+expert-sharded buffers lowers to an all-to-all.
 """
 from __future__ import annotations
 
+from functools import partial
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -27,14 +32,17 @@ PyTree = Any
 # ---------------------------------------------------------------------------
 
 
-def init_moe_mlp(b, name: str, d_model: int, d_ff: int, n_experts: int):
+def init_moe_mlp(b, name: str, d_model: int, d_ff: int, n_experts: int,
+                 held: int):
+    """The router over all ``n_experts``; SwiGLU weights of the ``held``
+    experts."""
     s = b.scope(name)
     s.param("router", (d_model, n_experts), ("embed", "experts"), init=scaled_init(0))
-    s.param("wi_gate", (n_experts, d_model, d_ff),
+    s.param("wi_gate", (held, d_model, d_ff),
             ("experts", "embed", "expert_mlp"), init=scaled_init(-2))
-    s.param("wi_up", (n_experts, d_model, d_ff),
+    s.param("wi_up", (held, d_model, d_ff),
             ("experts", "embed", "expert_mlp"), init=scaled_init(-2))
-    s.param("wo", (n_experts, d_ff, d_model),
+    s.param("wo", (held, d_ff, d_model),
             ("experts", "expert_mlp", "embed"), init=scaled_init(-2))
 
 
@@ -53,8 +61,23 @@ import os as _os
 MOE_IMPL = _os.environ.get("REPRO_MOE_IMPL", "auto")
 
 
-def moe_mlp(p: Dict, x: jax.Array, cfg: ModelConfig) -> Tuple[jax.Array, jax.Array]:
-    """x: (B, S, d) -> (out, aux_loss); dispatches on MOE_IMPL."""
+def moe_mlp(
+    p: Dict, x: jax.Array, cfg: ModelConfig
+) -> Tuple[jax.Array, jax.Array, Optional[jax.Array]]:
+    """x: (B, S, d) -> (out, aux_loss, rows).
+
+    Dropless configs take :func:`_moe_mlp_grouped`, whose ``rows`` counts
+    the layer's expert rows; capacity configs dispatch on MOE_IMPL and give
+    ``rows`` None."""
+    if cfg.capacity_factor is None:
+        return _moe_mlp_grouped(p, x, cfg)
+    if cfg.resolved_experts_held() != cfg.n_experts:
+        raise ValueError("a share of the experts needs dropless routing "
+                         "(capacity_factor None)")
+    return (*_moe_mlp_capacity(p, x, cfg), None)
+
+
+def _moe_mlp_capacity(p: Dict, x: jax.Array, cfg: ModelConfig) -> Tuple[jax.Array, jax.Array]:
     impl = MOE_IMPL
     if impl == "auto":
         from repro.compat import get_abstract_mesh
@@ -70,6 +93,127 @@ def moe_mlp(p: Dict, x: jax.Array, cfg: ModelConfig) -> Tuple[jax.Array, jax.Arr
     if impl == "fused":
         return _moe_mlp_fused(p, x, cfg)
     return _moe_mlp_dense(p, x, cfg)
+
+
+def _tile_rows(group_sizes: jax.Array, tm: int) -> jax.Array:
+    """Rows the grouped matmul's row tiles of ``tm`` cover: a group runs
+    every tile from the one holding its first row to the one holding its
+    last, so a tile shared by two groups is run (and counted) twice."""
+    ends = jnp.cumsum(group_sizes)
+    starts = ends - group_sizes
+    tiles = (ends + tm - 1) // tm - starts // tm
+    return jnp.sum(jnp.where(group_sizes > 0, tiles, 0)) * tm
+
+
+# The dispatch into the sorted buffer and the combine out of it are row
+# gathers whose transposes are gathers too (``order`` and ``inv`` are inverse
+# permutations of the token copies), so neither backward pass needs a
+# scatter-add.
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _dispatch(xf: jax.Array, order: jax.Array, inv: jax.Array, k: int) -> jax.Array:
+    """Buffer row i holds token ``order[i] // k``: (M, d) from xf (T, d)."""
+    return xf[order // k]
+
+
+def _dispatch_fwd(xf, order, inv, k):
+    return _dispatch(xf, order, inv, k), inv
+
+
+def _dispatch_bwd(k, inv, dxs):
+    # token t gets the rows of its k copies that are in the buffer
+    M = dxs.shape[0]
+    row = inv.reshape(-1, k)
+    parts = dxs[jnp.minimum(row, M - 1)].astype(jnp.float32)
+    dxf = jnp.sum(jnp.where((row < M)[..., None], parts, 0.0), axis=1)
+    return dxf.astype(dxs.dtype), None, None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _combine(ys: jax.Array, w: jax.Array, inv: jax.Array, order: jax.Array) -> jax.Array:
+    """Token t's output: sum over its copies j of w[t, j] x buffer row
+    ``inv[t*k + j]`` (a copy past the buffer has w 0).  ys (M, d), w (T, k)."""
+    T, k = w.shape
+    rows = ys[jnp.minimum(inv, ys.shape[0] - 1).reshape(T, k)]
+    return jnp.sum(rows.astype(jnp.float32) * w[..., None], axis=1).astype(ys.dtype)
+
+
+def _combine_fwd(ys, w, inv, order):
+    return _combine(ys, w, inv, order), (ys, w, inv, order)
+
+
+def _combine_bwd(res, dout):
+    ys, w, inv, order = res
+    T, k = w.shape
+    g = dout.astype(jnp.float32)
+    dys = g[order // k] * w.reshape(-1)[order][:, None]
+    rows = ys[jnp.minimum(inv, ys.shape[0] - 1).reshape(T, k)]
+    dw = jnp.einsum("tkd,td->tk", rows.astype(jnp.float32), g)
+    return dys.astype(ys.dtype), dw, None, None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+def _moe_mlp_grouped(
+    p: Dict, x: jax.Array, cfg: ModelConfig
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Dropless routing over all ``n_experts``; this chip computes its held
+    experts' part of the result.
+
+    The router's outputs stay in f32: softmax, top-k, and the k gates
+    renormalised.  Token copies routed to a held expert are sorted by expert
+    into a static buffer of T·min(k, held) rows (the most that can arrive);
+    one grouped matmul with per-expert row counts runs gate and up, then
+    SwiGLU, then one runs down, and the gated combine adds each token's
+    copies back.  Copies routed to experts held elsewhere are not computed
+    here (on one chip the layer runs without its exchange); none is dropped.
+    The Switch aux loss, E·Σ_e (count_e/T)·p_e, spans all E router outputs.
+
+    Returns (out, aux, rows): ``rows`` int32[2] is the copies routed to held
+    experts and the rows the grouped matmul's tiles cover.
+    """
+    from repro.kernels import ops as kops
+
+    B, S, d = x.shape
+    T = B * S
+    E, k = cfg.n_experts, cfg.experts_per_token
+    held = cfg.resolved_experts_held()
+    M = T * min(k, held)
+    xf = x.reshape(T, d)
+    with jax.named_scope("moe_router"):
+        logits = xf.astype(jnp.float32) @ p["router"].astype(jnp.float32)
+        probs = jax.nn.softmax(logits, axis=-1)                 # (T, E)
+        gate, expert = jax.lax.top_k(probs, k)                  # (T, k)
+        gate = gate / jnp.sum(gate, axis=-1, keepdims=True)
+        count = jnp.bincount(expert.reshape(-1), length=E)
+        aux = E * jnp.sum(count / T * jnp.mean(probs, axis=0))
+    with jax.named_scope("moe_dispatch"):
+        local = expert.reshape(-1) - cfg.first_expert           # (T*k,)
+        is_held = (local >= 0) & (local < held)
+        key = jnp.where(is_held, local, held)                   # elsewhere: last
+        order = jnp.argsort(key, stable=True)                   # copies by expert
+        inv = jnp.argsort(order)                                # copy -> row
+        group_sizes = jnp.bincount(key, length=held + 1)[:held].astype(jnp.int32)
+        xs = _dispatch(xf, order[:M], inv, k)                   # (M, d)
+    with jax.named_scope("moe_experts"):
+        w_gu = jnp.concatenate([p["wi_gate"], p["wi_up"]], axis=-1)
+        g, u = jnp.split(
+            kops.grouped_matmul(xs, w_gu.astype(x.dtype), group_sizes), 2,
+            axis=-1)
+        ys = kops.grouped_matmul(jax.nn.silu(g) * u,
+                                 p["wo"].astype(x.dtype), group_sizes)
+    with jax.named_scope("moe_combine"):
+        w = jnp.where(is_held.reshape(T, k), gate, 0.0)
+        out = _combine(ys, w, inv, order[:M])
+        out = wlc(out.reshape(B, S, d), "batch", "seq", "act_embed")
+    routed = jnp.sum(group_sizes)
+    rows = jnp.stack([routed, _tile_rows(group_sizes, kops.GMM_TILE_M)])
+    return out, aux.astype(jnp.float32), rows.astype(jnp.int32)
 
 
 def _moe_mlp_fused(p: Dict, x: jax.Array, cfg: ModelConfig) -> Tuple[jax.Array, jax.Array]:
@@ -304,10 +448,12 @@ def _init_block(s, cfg: ModelConfig):
     hd = cfg.resolved_head_dim()
     L.init_rmsnorm(s, "ln1", cfg.d_model)
     L.init_attention(
-        s, "attn", cfg.d_model, cfg.n_heads, cfg.n_kv_heads, hd, qkv_bias=cfg.qkv_bias
+        s, "attn", cfg.d_model, cfg.n_heads, cfg.n_kv_heads, hd,
+        qkv_bias=cfg.qkv_bias, qk_norm=cfg.qk_norm,
     )
     L.init_rmsnorm(s, "ln2", cfg.d_model)
-    init_moe_mlp(s, "moe", cfg.d_model, cfg.d_ff, cfg.n_experts)
+    init_moe_mlp(s, "moe", cfg.d_model, cfg.d_ff, cfg.n_experts,
+                 cfg.resolved_experts_held())
 
 
 def init_params(cfg: ModelConfig, key=None, abstract=False, dtype=None):
@@ -324,45 +470,55 @@ def init_params(cfg: ModelConfig, key=None, abstract=False, dtype=None):
 
 
 def _block_train(lp, x, cfg: ModelConfig, positions):
-    h = L.rms_norm(lp["ln1"], x)
-    h = L.attention_train(
-        lp["attn"], h, positions=positions, causal=True, window=cfg.window,
-        rope_theta=cfg.rope_theta, precision=cfg.train_precision,
-    )
-    x = x + h
-    h = L.rms_norm(lp["ln2"], x)
-    y, aux = moe_mlp(lp["moe"], h, cfg)
-    return x + y, aux
+    # the same scopes as dense.py's, so a device trace splits this step too
+    with jax.named_scope("attention"):
+        h = L.rms_norm(lp["ln1"], x)
+        h = L.attention_train(
+            lp["attn"], h, positions=positions, causal=True, window=cfg.window,
+            rope_theta=cfg.rope_theta, precision=cfg.train_precision,
+        )
+        x = x + h
+    with jax.named_scope("mlp"):
+        h = L.rms_norm(lp["ln2"], x)
+        y, aux, rows = moe_mlp(lp["moe"], h, cfg)
+        return x + y, aux, rows
 
 
-def forward(params, cfg: ModelConfig, tokens, **_) -> Tuple[jax.Array, jax.Array]:
-    """-> (logits, total_aux_loss)."""
-    x = L.embed(params["embedding"], tokens, cfg.dtype)
+def forward(params, cfg: ModelConfig, tokens, **_):
+    """-> (logits, total_aux_loss), plus ``{"moe_rows": int32[2]}`` (the
+    grouped path's rows, summed over layers) when dropless."""
+    with jax.named_scope("vocab"):
+        x = L.embed(params["embedding"], tokens, cfg.dtype)
     positions = jnp.arange(x.shape[1])
+    dropless = cfg.capacity_factor is None
 
     def body(lp, h):
-        return _block_train(lp, h, cfg, positions)
+        h, a, rows = _block_train(lp, h, cfg, positions)
+        return h, a, (rows if dropless else jnp.zeros((2,), jnp.int32))
 
     fn = jax.checkpoint(body) if cfg.remat else body
+    carry = (x, jnp.zeros((), jnp.float32), jnp.zeros((2,), jnp.int32))
     if cfg.scan_layers:
         def step(carry, lp):
-            h, aux = carry
-            h, a = fn(lp, h)
-            return (h, aux + a), None
+            h, aux, rows = carry
+            h, a, r = fn(lp, h)
+            return (h, aux + a, rows + r), None
 
         with L.repeated_layers(cfg.n_layers):
-            (x, aux), _ = jax.lax.scan(
-                step, (x, jnp.zeros((), jnp.float32)), params["blocks"])
+            (x, aux, rows), _ = jax.lax.scan(step, carry, params["blocks"])
     else:
-        aux = jnp.zeros((), jnp.float32)
+        x, aux, rows = carry
         for i in range(cfg.n_layers):
             lp = jax.tree_util.tree_map(lambda a: a[i], params["blocks"])
-            x, a = fn(lp, x)
-            aux = aux + a
+            x, a, r = fn(lp, x)
+            aux, rows = aux + a, rows + r
 
     from repro.models.dense import _final
 
-    return _final(params, x, cfg), aux
+    logits = _final(params, x, cfg)
+    if dropless:
+        return logits, aux, {"moe_rows": rows}
+    return logits, aux
 
 
 def init_cache(cfg: ModelConfig, batch: int, cache_len: int, dtype=None):
@@ -390,7 +546,7 @@ def prefill(params, cfg: ModelConfig, tokens, cache_len: int, **_):
         )
         h = h + attn_out
         hn = L.rms_norm(lp["ln2"], h)
-        y, _aux = moe_mlp(lp["moe"], hn, cfg)
+        y, _aux, _rows = moe_mlp(lp["moe"], hn, cfg)
         return h + y, kv
 
     fn = jax.checkpoint(body) if cfg.remat else body
@@ -420,7 +576,7 @@ def decode_step(params, cfg: ModelConfig, token, cache, pos):
         )
         h = h + attn_out
         hn = L.rms_norm(lp["ln2"], h)
-        y, _aux = moe_mlp(lp["moe"], hn, cfg)
+        y, _aux, _rows = moe_mlp(lp["moe"], hn, cfg)
         return h + y, kv
 
     from repro.models.dense import _final, _maybe_unrolled_scan

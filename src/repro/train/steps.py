@@ -53,15 +53,16 @@ def loss_fn(
     model: Model,
     params: PyTree,
     batch: Dict[str, jax.Array],
-    *,
-    aux_weight: float = 0.01,
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    """Masked global-mean LM loss (+ router aux for MoE)."""
+    """Masked global-mean LM loss (+ the router aux for MoE, weighted by
+    ``router_aux_weight`` of the model's config); the parts also carry the
+    forward's counters (``Model.forward_with_stats``)."""
     kwargs = {}
     for k in ("frames", "patch_embeds"):
         if k in batch:
             kwargs[k] = batch[k]
-    logits, aux = model.forward(params, batch["tokens"], **kwargs)
+    logits, aux, stats = model.forward_with_stats(
+        params, batch["tokens"], **kwargs)
     # VLM: logits cover [patches | text]; score text positions only
     labels = batch["labels"]
     mask = batch["loss_mask"]
@@ -70,8 +71,8 @@ def loss_fn(
     with jax.named_scope("vocab"):
         ce = cross_entropy(logits, labels)
         loss = masked_mean_loss(ce, mask)
-    total = loss + aux_weight * aux
-    return total, {"loss": loss, "aux": aux, "tokens": jnp.sum(mask)}
+    total = loss + model.cfg.router_aux_weight * aux
+    return total, {"loss": loss, "aux": aux, "tokens": jnp.sum(mask), **stats}
 
 
 def make_train_step(
@@ -79,7 +80,6 @@ def make_train_step(
     optimizer: Optimizer,
     lr_schedule: Callable[[jax.Array], jax.Array],
     *,
-    aux_weight: float = 0.01,
     grad_transform: Optional[Callable[[PyTree], PyTree]] = None,
 ) -> Callable:
     """Returns ``train_step(params, opt_state, batch) -> (params, opt_state, metrics)``.
@@ -90,7 +90,7 @@ def make_train_step(
 
     def train_step(params, opt_state: OptState, batch):
         (total, parts), grads = jax.value_and_grad(
-            lambda p: loss_fn(model, p, batch, aux_weight=aux_weight), has_aux=True
+            lambda p: loss_fn(model, p, batch), has_aux=True
         )(params)
         if grad_transform is not None:
             grads = grad_transform(grads)
@@ -105,31 +105,20 @@ def make_train_step(
                 sum(jnp.sum(jnp.square(g.astype(jnp.float32)))
                     for g in jax.tree_util.tree_leaves(grads))
             )
-        metrics = {
-            "loss": parts["loss"],
-            "aux": parts["aux"],
-            "total": total,
-            "lr": lr,
-            "grad_norm": gnorm,
-            "tokens": parts["tokens"],
-        }
+        metrics = dict(parts, total=total, lr=lr, grad_norm=gnorm)
         return params, opt_state, metrics
 
     return train_step
 
 
-def make_partial_grad_step(
-    model: Model,
-    *,
-    aux_weight: float = 0.01,
-) -> Callable:
+def make_partial_grad_step(model: Model) -> Callable:
     """The per-host half of cluster (hostsync) training.
 
     Returns ``grad_step(params, batch) -> (grads, sums)`` computing this
     host's UNNORMALIZED contribution to the global objective over its local
     rows only:
 
-        F_p(params) = Σ_p mask·ce  +  aux_weight · den_p · aux_p
+        F_p(params) = Σ_p mask·ce  +  router_aux_weight · den_p · aux_p
         sums        = {num: Σ mask·ce, den: Σ mask, auxden: den_p · aux_p}
 
     The global masked-mean step is ``total = (Σ_p F_p) / max(Σ_p den_p, 1)``
@@ -156,7 +145,7 @@ def make_partial_grad_step(
         num = jnp.sum(ce * mask)
         den = jnp.sum(mask)
         auxden = den * aux
-        return num + aux_weight * auxden, {
+        return num + model.cfg.router_aux_weight * auxden, {
             "num": num, "den": den, "auxden": auxden,
         }
 
@@ -251,14 +240,12 @@ def plan_buckets(params_abs: PyTree, n_buckets: int) -> Tuple[Tuple[int, ...], .
 def make_bucketed_grad_step(
     model: Model,
     bucket_groups: Tuple[Tuple[int, ...], ...],
-    *,
-    aux_weight: float = 0.01,
 ) -> Callable:
     """:func:`make_partial_grad_step` with the grad pytree flattened into
     one f32 vector per bucket group — the cluster transport's wire format.
     Returns ``grad_step(params, batch) -> (bucket_vecs, sums)``.
     """
-    base = make_partial_grad_step(model, aux_weight=aux_weight)
+    base = make_partial_grad_step(model)
 
     def grad_step(params, batch):
         grads, sums = base(params, batch)
@@ -311,9 +298,7 @@ def make_bucketed_apply_step(
     return apply_step
 
 
-def residual_bytes(
-    model: Model, batch_abs: Dict[str, Any], *, aux_weight: float = 0.01
-) -> int:
+def residual_bytes(model: Model, batch_abs: Dict[str, Any]) -> int:
     """Bytes of saved-for-backward residuals of one loss VJP (no allocation).
 
     ``jax.vjp``'s pullback is a Partial pytree whose leaves ARE the residual
@@ -325,7 +310,7 @@ def residual_bytes(
 
     def f(params, batch):
         _, pullback = jax.vjp(
-            lambda p: loss_fn(model, p, batch, aux_weight=aux_weight)[0],
+            lambda p: loss_fn(model, p, batch)[0],
             params,
         )
         return pullback
@@ -337,9 +322,9 @@ def residual_bytes(
     ))
 
 
-def make_eval_step(model: Model, *, aux_weight: float = 0.01) -> Callable:
+def make_eval_step(model: Model) -> Callable:
     def eval_step(params, batch):
-        _, parts = loss_fn(model, params, batch, aux_weight=aux_weight)
+        _, parts = loss_fn(model, params, batch)
         return parts
 
     return eval_step

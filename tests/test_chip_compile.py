@@ -11,7 +11,9 @@ use.  Widths:
     ``jax.grad``) also at deepseek-coder-33b's 56 query heads over 8 kv heads;
   * fused MoE at qwen3-moe-30b-a3b's d_model 2048, expert d_ff 768, top-8,
     with 32 experts held (128 over 4 chips) and 2048 tokens;
-  * rwkv6 at rwkv6-7b's 64 heads x 64.
+  * rwkv6 at rwkv6-7b's 64 heads x 64;
+  * the whole training step of qwen3-moe-30b-a3b's one-chip share, with its
+    grouped matmul, at 8 rows x 4096 (it has to fit the chip's 16 GiB).
 
 The topology is described inside a fixture (never at import or collection),
 so each pytest-xdist worker collects the same tests and only the worker that
@@ -159,3 +161,33 @@ def test_kernel_compiles_for_v5e(name, one_chip, no_persistent_cache):
             for shape, dtype in specs]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_qwen3_moe_share_step_compiles_for_v5e(one_chip, no_persistent_cache,
+                                               monkeypatch):
+    """The whole training step of one chip's share of qwen3-moe-30b-a3b
+    (4 layers, 16 of 128 experts, an eighth of the vocabulary) at 8 rows x
+    4096, on the TPU's paths (splash attention, the megablox grouped
+    matmul), fits one v5e's 16 GiB."""
+    from repro.configs.qwen3_moe_30b_a3b import CONFIG
+    from repro.models.api import get_model
+    from repro.optim import adamw
+    from repro.train.steps import make_train_step
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(ops, "interpret_default", lambda: False)
+    model = get_model(CONFIG.with_(n_layers=4, vocab=18992, experts_held=16))
+    opt = adamw(b1=0.9, b2=0.95, eps=1e-8, weight_decay=0.1)
+    step = make_train_step(model, opt, lambda s: jnp.float32(1e-3))
+    params, _ = model.init_params(abstract=True)
+    on_chip = lambda t: jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), t)
+    rows = {k: jax.ShapeDtypeStruct((8, 4096), dt, sharding=one_chip)
+            for k, dt in (("tokens", i32), ("labels", i32), ("loss_mask", f32))}
+    compiled = jax.jit(step, donate_argnums=(0, 1)).lower(
+        on_chip(params), on_chip(jax.eval_shape(opt.init, params)), rows
+    ).compile()
+    text = compiled.as_text()
+    assert "splash_mha_fwd" in text and "gmm" in text and "tgmm" in text
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16 * 2**30

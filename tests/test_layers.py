@@ -187,3 +187,70 @@ def test_tpu_route_leaves_other_attention_bit_identical(case, monkeypatch):
         after = jax.jit(lambda p, x: call(p, x, pos))(p, x)
     assert "splash" not in paths
     np.testing.assert_array_equal(np.asarray(after), np.asarray(before))
+
+
+def _qk_attn_params(qk_norm, seed=5):
+    from repro.models.param import build
+
+    def f(b):
+        L.init_attention(b, "attn", 32, 4, 2, 16, qk_norm=qk_norm)
+
+    params, _ = build(f, key=jax.random.PRNGKey(seed))
+    return params["attn"]
+
+
+def test_qk_norm_is_a_per_head_rms_norm_before_rope():
+    """q and k of each head are RMS-normed over head_dim with one scale of
+    head_dim shared by the heads; v is not."""
+    p = _qk_attn_params(True)
+    assert p["q_norm"]["scale"].shape == p["k_norm"]["scale"].shape == (16,)
+    ks = jax.random.split(KEY, 3)
+    p = dict(p, q_norm={"scale": jax.random.uniform(ks[0], (16,)) + 0.5},
+             k_norm={"scale": jax.random.uniform(ks[1], (16,)) + 0.5})
+    x = jax.random.normal(ks[2], (2, 8, 32))
+    q, k, v = L.qkv_project(p, x)
+
+    def plain(w, scale):
+        y = np.einsum("bsd,dhk->bshk", np.asarray(x, np.float64),
+                      np.asarray(w, np.float64))
+        rms = np.sqrt(np.mean(y * y, axis=-1, keepdims=True) + 1e-6)
+        return y / rms * np.asarray(scale, np.float64)
+
+    np.testing.assert_allclose(q, plain(p["wq"], p["q_norm"]["scale"]),
+                               rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(k, plain(p["wk"], p["k_norm"]["scale"]),
+                               rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(v, jnp.einsum("bsd,dhk->bshk", x, p["wv"]),
+                               rtol=1e-6, atol=1e-6)
+
+
+def test_qk_norm_off_leaves_attention_as_it_was():
+    """Without the field there are no norm params and q, k, v are the bare
+    projections, in training, prefill and decode alike."""
+    p = _qk_attn_params(False)
+    assert "q_norm" not in p and "k_norm" not in p
+    x = jax.random.normal(KEY, (2, 8, 32))
+    q, k, v = L.qkv_project(p, x)
+    for t, w in ((q, "wq"), (k, "wk"), (v, "wv")):
+        np.testing.assert_array_equal(
+            t, jnp.einsum("bsd,dhk->bshk", x, p[w]))
+    on = _qk_attn_params(True)
+    on = dict(on, **{w: p[w] for w in ("wq", "wk", "wv", "wo")})
+    pos = jnp.arange(8)
+    off_out = L.attention_train(p, x, positions=pos)
+    assert not np.allclose(L.attention_train(on, x, positions=pos), off_out)
+    pre, cache = L.attention_prefill(p, x, positions=pos, cache_len=8)
+    np.testing.assert_allclose(pre, off_out, rtol=1e-6, atol=1e-6)
+
+
+def test_qk_norm_decode_matches_training_attention():
+    """With QK-norm on, prefill of 7 tokens then one decode step give the
+    training attention's last position."""
+    p = _qk_attn_params(True)
+    x = jax.random.normal(KEY, (2, 8, 32))
+    full = L.attention_train(p, x, positions=jnp.arange(8))
+    _, cache = L.attention_prefill(p, x[:, :7], positions=jnp.arange(7),
+                                   cache_len=8)
+    last, _ = L.attention_decode(p, x[:, 7:], cache,
+                                 pos=jnp.full((2,), 7, jnp.int32))
+    np.testing.assert_allclose(last[:, 0], full[:, 7], rtol=1e-5, atol=1e-5)

@@ -377,3 +377,46 @@ def test_attention_paths_count_layers_on_cpu(monkeypatch):
     assert s.model.cfg.scan_layers and n_layers > 1
     assert rep.attention_paths == {"chunked": n_layers}
     assert s.compile().attention_paths == {"chunked": n_layers}
+
+
+def _moe_session(steps=2, **model_kw):
+    cfg = smoke_config("qwen3-moe-30b-a3b").with_(**model_kw)
+    spec = FleetSpec.demo(1)
+    return Session(
+        model=get_model(cfg),
+        optimizer=adamw(),
+        fleet=spec,
+        data=DataConfig(vocab=cfg.vocab, seq_len=16),
+        shards=spec.shards(private_per_worker={"csd": 64}, public=4096),
+        config=SessionConfig(total_steps=steps),
+    )
+
+
+def test_moe_run_counts_expert_rows_in_one_more_read():
+    """A dropless MoE step reports its expert rows: Session.run adds them
+    to the process-wide counters with one read more than a dense step's."""
+    from repro.api.spans import counters
+
+    s = _moe_session(steps=2)
+    cfg = s.model.cfg
+    before = counters()
+    rep = s.run()
+    after = counters()
+    grown = {k: after[k] - before.get(k, 0)
+             for k in ("moe_rows_routed", "moe_rows_computed")}
+    rows = s.tune().schedule.global_rows
+    # every expert is held: each token's k copies are routed here, per layer
+    assert grown["moe_rows_routed"] == (
+        2 * cfg.n_layers * rows * 16 * cfg.experts_per_token)
+    assert grown["moe_rows_computed"] >= grown["moe_rows_routed"]
+    assert rep.readbacks == 7 * rep.steps_run
+    assert all("moe_rows" not in h for h in rep.history)
+
+
+@pytest.mark.parametrize("weight", [0.001, 0.5])
+def test_moe_aux_weight_comes_from_the_model_config(weight):
+    s = _moe_session(steps=1, router_aux_weight=weight)
+    h = s.run().history[0]
+    assert h["aux"] > 0
+    np.testing.assert_allclose(h["total"], h["loss"] + weight * h["aux"],
+                               rtol=1e-6)
