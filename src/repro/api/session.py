@@ -776,9 +776,11 @@ class Session:
                 rows = metrics.pop("moe_rows", None)
                 metrics = {k: float(v) for k, v in metrics.items()}
                 if rows is not None:
-                    routed, computed = np.asarray(rows).tolist()
+                    routed, computed, full = np.asarray(rows).tolist()
                     count("moe_rows_routed", routed)
                     count("moe_rows_computed", computed)
+                    count("moe_full_buffer_layers", full)
+                    count("moe_layer_steps", self.model.cfg.n_layers)
             readbacks += len(metrics) + (rows is not None)
             metrics["step_time"] = time.perf_counter() - ts
             metrics["feed_s"] = feed.seconds

@@ -150,13 +150,128 @@ def _combine_bwd(res, dout):
     ys, w, inv, order = res
     T, k = w.shape
     g = dout.astype(jnp.float32)
-    dys = g[order // k] * w.reshape(-1)[order][:, None]
+    # gathered in dout's dtype, widened after: the same numbers, and no
+    # (M, d) f32 copy
+    dys = dout[order // k].astype(jnp.float32) * w.reshape(-1)[order][:, None]
     rows = ys[jnp.minimum(inv, ys.shape[0] - 1).reshape(T, k)]
     dw = jnp.einsum("tkd,td->tk", rows.astype(jnp.float32), g)
     return dys.astype(ys.dtype), dw, None, None
 
 
 _combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+def _experts(xs: jax.Array, w_gu: jax.Array, wo: jax.Array,
+             group_sizes: jax.Array) -> jax.Array:
+    """Gate and up in one grouped matmul, SwiGLU, then down: (rows, d)."""
+    from repro.kernels import ops as kops
+
+    with jax.named_scope("moe_experts"):
+        g, u = jnp.split(kops.grouped_matmul(xs, w_gu, group_sizes), 2,
+                         axis=-1)
+        return kops.grouped_matmul(jax.nn.silu(g) * u, wo, group_sizes)
+
+
+def _full_buffer(xf, w, order, group_sizes, w_gu, wo):
+    """The layer's result over a buffer of every row that could arrive,
+    T·min(k, held): xf (T, d), w (T, k) the gates (0 for copies held
+    elsewhere), ``order`` the copies sorted by expert."""
+    T, k = w.shape
+    M = T * min(k, group_sizes.shape[0])
+    with jax.named_scope("moe_dispatch"):
+        inv = jnp.argsort(order)                                # copy -> row
+        xs = _dispatch(xf, order[:M], inv, k)                   # (M, d)
+    ys = _experts(xs, w_gu, wo, group_sizes)
+    with jax.named_scope("moe_combine"):
+        return _combine(ys, w, inv, order[:M])
+
+
+def _compact(xf, w, order, group_sizes, w_gu, wo, C):
+    """The same result over the first C rows of the sorted copies, exact
+    when every routed copy is among them: buffer row i is token
+    ``order[i] // k`` with gate ``w.flat[order[i]]``, and the combine adds
+    each row into its token in f32."""
+    T, k = w.shape
+    with jax.named_scope("moe_dispatch"):
+        tok = order[:C] // k
+        xs = xf[tok]                                            # (C, d)
+    ys = _experts(xs, w_gu, wo, group_sizes)
+    with jax.named_scope("moe_combine"):
+        wr = w.reshape(-1)[order[:C]]
+        out = jnp.zeros(xf.shape, jnp.float32).at[tok].add(
+            ys.astype(jnp.float32) * wr[:, None])
+        return out.astype(ys.dtype)
+
+
+def _compact_grads(xf, w, order, group_sizes, w_gu, wo, dout, C):
+    """:func:`_compact`'s gradients for (xf, w, w_gu, wo): C-row gathers
+    of the token rows, C-row scatter-adds back, f32 where the forward
+    accumulates."""
+    T, k = w.shape
+    with jax.named_scope("moe_dispatch"):
+        tok = order[:C] // k
+        xs = xf[tok]
+    ys, experts_vjp = jax.vjp(
+        lambda xs, a, b: _experts(xs, a, b, group_sizes), xs, w_gu, wo)
+    with jax.named_scope("moe_combine"):
+        wr = w.reshape(-1)[order[:C]]
+        g = dout[tok].astype(jnp.float32)
+        dwr = jnp.sum(ys.astype(jnp.float32) * g, axis=-1)
+        dw = jnp.zeros(T * k, dwr.dtype).at[order[:C]].set(dwr)
+        dys = (g * wr[:, None]).astype(ys.dtype)
+    dxs, dgu, dwo = experts_vjp(dys)
+    with jax.named_scope("moe_dispatch"):
+        dxf = jnp.zeros(xf.shape, jnp.float32).at[tok].add(
+            dxs.astype(jnp.float32))
+    return dxf.astype(xf.dtype), dw.reshape(T, k), dgu, dwo
+
+
+def _full_grads(xf, w, order, group_sizes, w_gu, wo, dout):
+    """:func:`_full_buffer`'s gradients for (xf, w, w_gu, wo)."""
+    _, vjp = jax.vjp(
+        lambda xf, w, a, b: _full_buffer(xf, w, order, group_sizes, a, b),
+        xf, w, w_gu, wo)
+    return vjp(dout)
+
+
+# Under the layer's checkpoint, differentiating a ``cond`` would save the
+# union of both branches' residuals (the full buffer's zero-filled when the
+# compact branch runs); this vjp saves only the inputs, and each branch of
+# its backward recomputes its own forward.
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(6,))
+def _routed_rows(xf, w, order, group_sizes, w_gu, wo, C):
+    """:func:`_compact` when the copies routed here fit its C rows, else
+    :func:`_full_buffer`; chosen on the device."""
+    return jax.lax.cond(jnp.sum(group_sizes) <= C, partial(_compact, C=C),
+                        _full_buffer, xf, w, order, group_sizes, w_gu, wo)
+
+
+def _routed_rows_fwd(xf, w, order, group_sizes, w_gu, wo, C):
+    res = (xf, w, order, group_sizes, w_gu, wo)
+    return _routed_rows(*res, C), res
+
+
+def _routed_rows_bwd(C, res, dout):
+    group_sizes = res[3]
+    dxf, dw, dgu, dwo = jax.lax.cond(
+        jnp.sum(group_sizes) <= C, partial(_compact_grads, C=C), _full_grads,
+        *res, dout)
+    return dxf, dw, None, None, dgu, dwo
+
+
+_routed_rows.defvjp(_routed_rows_fwd, _routed_rows_bwd)
+
+
+def compact_rows(n_tokens: int, k: int, held: int, n_experts: int) -> int:
+    """Rows of the compact buffer: twice the copies a uniform router sends
+    to ``held`` of ``n_experts``, in whole grouped-matmul tiles, at most
+    the full buffer's T·min(k, held)."""
+    from repro.kernels.ops import GMM_TILE_M
+
+    want = -(-2 * n_tokens * k * held // n_experts)
+    return min(n_tokens * min(k, held), -(-want // GMM_TILE_M) * GMM_TILE_M)
 
 
 def _moe_mlp_grouped(
@@ -166,16 +281,20 @@ def _moe_mlp_grouped(
     experts' part of the result.
 
     The router's outputs stay in f32: softmax, top-k, and the k gates
-    renormalised.  Token copies routed to a held expert are sorted by expert
-    into a static buffer of T·min(k, held) rows (the most that can arrive);
-    one grouped matmul with per-expert row counts runs gate and up, then
-    SwiGLU, then one runs down, and the gated combine adds each token's
-    copies back.  Copies routed to experts held elsewhere are not computed
+    renormalised.  Token copies routed to a held expert are sorted by
+    expert; one grouped matmul with per-expert row counts runs gate and up,
+    then SwiGLU, then one runs down, and the gated combine adds each token's
+    copies back.  The rows run through a compact buffer of
+    :func:`compact_rows` C rows when the copies routed here fit it, else
+    (decided on the device) through the static buffer of T·min(k, held)
+    rows, the most that can arrive; where C reaches that, the static
+    buffer alone.  Copies routed to experts held elsewhere are not computed
     here (on one chip the layer runs without its exchange); none is dropped.
     The Switch aux loss, E·Σ_e (count_e/T)·p_e, spans all E router outputs.
 
-    Returns (out, aux, rows): ``rows`` int32[2] is the copies routed to held
-    experts and the rows the grouped matmul's tiles cover.
+    Returns (out, aux, rows): ``rows`` int32[3] is the copies routed to held
+    experts, the rows the grouped matmul's tiles cover, and 1 if the layer
+    took the static buffer because the copies did not fit C rows, else 0.
     """
     from repro.kernels import ops as kops
 
@@ -183,7 +302,6 @@ def _moe_mlp_grouped(
     T = B * S
     E, k = cfg.n_experts, cfg.experts_per_token
     held = cfg.resolved_experts_held()
-    M = T * min(k, held)
     xf = x.reshape(T, d)
     with jax.named_scope("moe_router"):
         logits = xf.astype(jnp.float32) @ p["router"].astype(jnp.float32)
@@ -197,22 +315,22 @@ def _moe_mlp_grouped(
         is_held = (local >= 0) & (local < held)
         key = jnp.where(is_held, local, held)                   # elsewhere: last
         order = jnp.argsort(key, stable=True)                   # copies by expert
-        inv = jnp.argsort(order)                                # copy -> row
         group_sizes = jnp.bincount(key, length=held + 1)[:held].astype(jnp.int32)
-        xs = _dispatch(xf, order[:M], inv, k)                   # (M, d)
     with jax.named_scope("moe_experts"):
         w_gu = jnp.concatenate([p["wi_gate"], p["wi_up"]], axis=-1)
-        g, u = jnp.split(
-            kops.grouped_matmul(xs, w_gu.astype(x.dtype), group_sizes), 2,
-            axis=-1)
-        ys = kops.grouped_matmul(jax.nn.silu(g) * u,
-                                 p["wo"].astype(x.dtype), group_sizes)
     with jax.named_scope("moe_combine"):
         w = jnp.where(is_held.reshape(T, k), gate, 0.0)
-        out = _combine(ys, w, inv, order[:M])
-        out = wlc(out.reshape(B, S, d), "batch", "seq", "act_embed")
+    args = (xf, w, order, group_sizes, w_gu.astype(x.dtype),
+            p["wo"].astype(x.dtype))
+    C = compact_rows(T, k, held, E)
+    if C < T * min(k, held):
+        out = _routed_rows(*args, C)
+    else:
+        out = _full_buffer(*args)
+    out = wlc(out.reshape(B, S, d), "batch", "seq", "act_embed")
     routed = jnp.sum(group_sizes)
-    rows = jnp.stack([routed, _tile_rows(group_sizes, kops.GMM_TILE_M)])
+    rows = jnp.stack([routed, _tile_rows(group_sizes, kops.GMM_TILE_M),
+                      (routed > C).astype(routed.dtype)])
     return out, aux.astype(jnp.float32), rows.astype(jnp.int32)
 
 
@@ -485,7 +603,7 @@ def _block_train(lp, x, cfg: ModelConfig, positions):
 
 
 def forward(params, cfg: ModelConfig, tokens, **_):
-    """-> (logits, total_aux_loss), plus ``{"moe_rows": int32[2]}`` (the
+    """-> (logits, total_aux_loss), plus ``{"moe_rows": int32[3]}`` (the
     grouped path's rows, summed over layers) when dropless."""
     with jax.named_scope("vocab"):
         x = L.embed(params["embedding"], tokens, cfg.dtype)
@@ -494,10 +612,10 @@ def forward(params, cfg: ModelConfig, tokens, **_):
 
     def body(lp, h):
         h, a, rows = _block_train(lp, h, cfg, positions)
-        return h, a, (rows if dropless else jnp.zeros((2,), jnp.int32))
+        return h, a, (rows if dropless else jnp.zeros((3,), jnp.int32))
 
     fn = jax.checkpoint(body) if cfg.remat else body
-    carry = (x, jnp.zeros((), jnp.float32), jnp.zeros((2,), jnp.int32))
+    carry = (x, jnp.zeros((), jnp.float32), jnp.zeros((3,), jnp.int32))
     if cfg.scan_layers:
         def step(carry, lp):
             h, aux, rows = carry

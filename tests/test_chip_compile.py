@@ -168,7 +168,7 @@ def test_qwen3_moe_share_step_compiles_for_v5e(one_chip, no_persistent_cache,
     """The whole training step of one chip's share of qwen3-moe-30b-a3b
     (4 layers, 16 of 128 experts, an eighth of the vocabulary) at 8 rows x
     4096, on the TPU's paths (splash attention, the megablox grouped
-    matmul), fits one v5e's 16 GiB."""
+    matmul, the expert layer's choice of buffer), fits one v5e's 16 GiB."""
     from repro.configs.qwen3_moe_30b_a3b import CONFIG
     from repro.models.api import get_model
     from repro.optim import adamw
@@ -189,5 +189,7 @@ def test_qwen3_moe_share_step_compiles_for_v5e(one_chip, no_persistent_cache,
     ).compile()
     text = compiled.as_text()
     assert "splash_mha_fwd" in text and "gmm" in text and "tgmm" in text
+    # the expert layer picks its compact or its static buffer on the device
+    assert " conditional(" in text
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16 * 2**30

@@ -393,8 +393,9 @@ def _moe_session(steps=2, **model_kw):
 
 
 def test_moe_run_counts_expert_rows_in_one_more_read():
-    """A dropless MoE step reports its expert rows: Session.run adds them
-    to the process-wide counters with one read more than a dense step's."""
+    """A dropless MoE step reports its expert rows and the layers that took
+    the static buffer: Session.run adds them, and the layers it ran, to the
+    process-wide counters with one read more than a dense step's."""
     from repro.api.spans import counters
 
     s = _moe_session(steps=2)
@@ -403,12 +404,17 @@ def test_moe_run_counts_expert_rows_in_one_more_read():
     rep = s.run()
     after = counters()
     grown = {k: after[k] - before.get(k, 0)
-             for k in ("moe_rows_routed", "moe_rows_computed")}
+             for k in ("moe_rows_routed", "moe_rows_computed",
+                       "moe_full_buffer_layers", "moe_layer_steps")}
     rows = s.tune().schedule.global_rows
     # every expert is held: each token's k copies are routed here, per layer
     assert grown["moe_rows_routed"] == (
         2 * cfg.n_layers * rows * 16 * cfg.experts_per_token)
     assert grown["moe_rows_computed"] >= grown["moe_rows_routed"]
+    # every layer of both steps counted; all held, so C is the whole buffer
+    # and no layer falls back to it
+    assert grown["moe_layer_steps"] == 2 * cfg.n_layers
+    assert grown["moe_full_buffer_layers"] == 0
     assert rep.readbacks == 7 * rep.steps_run
     assert all("moe_rows" not in h for h in rep.history)
 
